@@ -809,4 +809,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import use_compile_cache
+    use_compile_cache()
     main()

@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import sharding
@@ -265,12 +266,17 @@ def make_sharded_dml_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
                           unroll: bool = False, impl: Optional[str] = None):
     """``make_dml_train_step`` device-sharded over a ``clients`` mesh axis.
 
-    Each device owns whole clients (round-robin spill for
-    n_clients > n_devices via ``stacking.client_layout``); private-shard CE
-    runs collective-free, and the ONLY cross-device traffic is one
-    all-gather of the public-batch logits (K_loc, B_pub*S, V) feeding the
-    Eq.-2 term — the paper's communication frontier as real collective
-    traffic (``comm_bytes``'s ``dml_round`` simulates exactly these bytes).
+    Each device owns a contiguous block of whole clients: device d holds
+    clients d*K_loc .. (d+1)*K_loc - 1 with K_loc = ceil(K / n_devices)
+    (``sharded_client_layout``).  Where n_devices does not divide K, the
+    fleet is padded with wrapped copies of real clients whose updates are
+    masked out and dropped.  The stacked state keeps that blocked layout
+    as it is stored (``client_sharding``), so params and optimizer
+    moments never leave their device; private-shard CE runs
+    collective-free, and the ONLY cross-device traffic is one all-gather
+    of the public-batch logits (K_loc, B_pub*S, V) feeding the Eq.-2 term
+    — the paper's communication frontier as real collective traffic
+    (``comm_bytes``'s ``dml_round`` simulates exactly these bytes).
 
     Two deliberate deltas vs the unsharded step:
       - grad clipping is per client (``clip_norm`` applies to each client's
@@ -288,12 +294,14 @@ def make_sharded_dml_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
         raise ValueError("sharded DML step: prefix-conditioned archs are "
                          "not supported yet")
     n_dev = mesh.shape[stacking.CLIENT_AXIS]
-    k_loc, k_pad = stacking.client_layout(n_clients, n_dev)
+    k_loc, k_pad = sharded_client_layout(n_clients, n_dev)
     spec = stacking.client_spec()
     opt_noclip = dataclasses.replace(opt_cfg, clip_norm=None)
+    state_sharding = client_sharding(mesh, n_clients)
 
     def body(params, opt, tokens, public_tokens, pm_full):
-        gids = stacking.local_client_ids(n_clients, n_dev)
+        gids = (jax.lax.axis_index(stacking.CLIENT_AXIS) * k_loc
+                + jnp.arange(k_loc))
         pm_loc = jnp.take(pm_full, gids)
         pair_w = jnp.take(_pair_mask(k_pad, pm_full), gids, axis=0)
 
@@ -308,8 +316,10 @@ def make_sharded_dml_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
                                                 impl))(sp)
             K_l, B, S, V = fwd.shape
             flat = fwd.reshape(K_l, B * S, V)
-            gathered = stacking.gather_clients(
-                jax.lax.stop_gradient(flat), n_clients, n_dev)
+            # blocked layout: the tiled gather is already in client order
+            gathered = jax.lax.all_gather(
+                jax.lax.stop_gradient(flat), stacking.CLIENT_AXIS, axis=0,
+                tiled=True)
             kl = jnp.mean(ops.mutual_kl_pair(
                 flat, gathered, pair_w, temperature=temperature,
                 impl=impl), axis=-1)                          # (K_loc,)
@@ -340,24 +350,50 @@ def make_sharded_dml_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
         in_specs=(spec, opt_spec, spec, P(), P()),
         out_specs=(spec, opt_spec, met_spec))
 
+    wrap = np.arange(k_pad) % n_clients
+
+    def pad(tree):
+        if k_pad == n_clients:
+            return tree
+        return jax.tree.map(lambda t: jnp.take(t, wrap, axis=0), tree)
+
+    def unpad(tree):
+        return jax.tree.map(lambda t: jax.lax.with_sharding_constraint(
+            t[:n_clients], state_sharding), tree)
+
     def step(stacked_params, opt_state, tokens, public_tokens,
              part_mask=None):
         pm = jnp.ones((n_clients,), jnp.float32) if part_mask is None \
             else jnp.asarray(part_mask, jnp.float32)
-        pm_nat = jnp.zeros((k_pad,), jnp.float32).at[:n_clients].set(pm)
-        shard = lambda t: stacking.shard_clients(t, n_clients, n_dev)
+        pm_pad = jnp.zeros((k_pad,), jnp.float32).at[:n_clients].set(pm)
         new_p, new_o, met = run(
-            shard(stacked_params),
-            {"mu": shard(opt_state["mu"]), "nu": shard(opt_state["nu"]),
+            pad(stacked_params),
+            {"mu": pad(opt_state["mu"]), "nu": pad(opt_state["nu"]),
              "step": opt_state["step"]},
-            shard(tokens), public_tokens, pm_nat)
-        unshard = lambda t: stacking.unshard_clients(t, n_clients, n_dev)
-        met = {k: (unshard(v) if k != "lr" else v) for k, v in met.items()}
-        return unshard(new_p), \
-            {"mu": unshard(new_o["mu"]), "nu": unshard(new_o["nu"]),
+            pad(tokens), public_tokens, pm_pad)
+        met = {k: (unpad(v) if k != "lr" else v) for k, v in met.items()}
+        return unpad(new_p), \
+            {"mu": unpad(new_o["mu"]), "nu": unpad(new_o["nu"]),
              "step": new_o["step"]}, met
 
     return step
+
+
+def sharded_client_layout(n_clients: int, n_devices: int):
+    """(K_loc, K_pad) of ``make_sharded_dml_step``'s blocked layout:
+    K_loc = ceil(K / n_devices) clients per device, K_pad = n_devices *
+    K_loc slots in all (the last K_pad - K are masked wrapped copies)."""
+    k_loc = -(-n_clients // n_devices)
+    return k_loc, n_devices * k_loc
+
+
+def client_sharding(mesh, n_clients: int):
+    """Where ``make_sharded_dml_step`` keeps a K-stacked state leaf: one
+    block of clients per device when n_devices divides K, replicated
+    otherwise (an uneven K cannot be split into equal blocks)."""
+    n_dev = mesh.shape[stacking.CLIENT_AXIS]
+    spec = stacking.client_spec() if n_clients % n_dev == 0 else P()
+    return jax.sharding.NamedSharding(mesh, spec)
 
 
 # ---------------------------------------------------------------------------

@@ -45,8 +45,9 @@ def mutual_kl_terms_vs(live_logits, fixed_logits, pair_w,
     out[i, b] = sum_j pair_w[i, j] * KL(softmax(live_i) || softmax(fixed_j))
     with explicit (Kl, Kg) pair weights.  This is the device-local shard of
     ``mutual_kl_terms``: rows are this device's clients, columns the
-    all-gathered fleet (``stacking.gather_clients``), and ``pair_w`` the
-    matching rows of ``_pair_mask``.  The math IS the kernel oracle.
+    all-gathered fleet (``distributed.make_sharded_dml_step``), and
+    ``pair_w`` the matching rows of ``_pair_mask``.  The math IS the
+    kernel oracle.
     """
     return ref.mutual_kl_pair(live_logits, fixed_logits, pair_w,
                               temperature=temperature)

@@ -23,6 +23,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as D
 from repro.core import stacking
@@ -53,14 +54,27 @@ class LMClients(Population):
         self.seed = seed
         self.mesh = mesh
         # kernel impl policy is resolved ONCE here ("auto" -> pallas on TPU,
-        # ref elsewhere, REPRO_KERNEL_IMPL overrides) and threaded through
-        # every step factory as a plain argument — the jitted hot path never
+        # ref elsewhere; see ops.resolve_impl) and threaded through every
+        # step factory as a plain argument — the jitted hot path never
         # reads the ambient ops.get_impl() state
         self.impl = ops.resolve_impl(kernel_impl)
         self.opt_cfg = AdamWConfig(lr=lr, warmup=5, total_steps=rounds)
         key = jax.random.PRNGKey(seed)
-        self.client_params = D.stacked_init(key, cfg, n_clients)
-        self.client_opts = D.stacked_adamw_init(self.client_params)
+
+        def init(k):
+            params = D.stacked_init(k, cfg, n_clients)
+            return params, D.stacked_adamw_init(params)
+
+        if mesh is None:
+            self.client_params, self.client_opts = init(key)
+        else:
+            # built where the sharded step keeps it: no device ever holds
+            # the whole fleet's params and optimizer moments
+            sh = D.client_sharding(mesh, n_clients)
+            rep = NamedSharding(mesh, P())
+            self.client_params, self.client_opts = jax.jit(
+                init, out_shardings=(sh, {"mu": sh, "nu": sh, "step": rep})
+            )(key)
         self._steps = {}
         self._last_metrics = {}
 

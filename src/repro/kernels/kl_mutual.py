@@ -121,12 +121,25 @@ def _kl_pair_kernel(live_ref, fixed_ref, w_ref, out_ref,
                                axis=1).astype(out_ref.dtype)
 
 
+# bytes the pair kernel's (Kl, Kg, bb, bv) fp32 difference tensor may take
+# in VMEM: the TPU's scoped VMEM limit is 16 MiB, and the difference, its
+# product with exp(g) and the double-buffered input blocks share it
+_DIFF_VMEM_BYTES = 4 * 2 ** 20
+
+
+def _pair_block_v(Kl: int, Kg: int, bb: int) -> int:
+    """Largest vocab block (a multiple of 128) whose difference tensor
+    fits ``_DIFF_VMEM_BYTES``: 2048 at Kl = Kg = 2, 512 at Kl = 2, Kg = 8
+    (one device of a four-chip mesh holding two of eight clients)."""
+    return max(128, _DIFF_VMEM_BYTES // (Kl * Kg * bb * 4) // 128 * 128)
+
+
 def _kl_pair_forward(live, fixed, pair_w, temperature: float,
                      interpret: bool, block_b: int, block_v: int):
     Kl, B, V = live.shape
     Kg = fixed.shape[0]
     bb = min(block_b, B)
-    bv = min(block_v, V)
+    bv = min(block_v, V, _pair_block_v(Kl, Kg, bb))
     pad_b = (-B) % bb
     pad_v = (-V) % bv
     if pad_b or pad_v:

@@ -69,19 +69,28 @@ def use_impl(impl: str):
 def resolve_impl(impl: Optional[str] = None) -> str:
     """Resolve the kernel-impl policy ONCE, at construction time.
 
-    Priority: explicit value > REPRO_KERNEL_IMPL env > backend default —
-    ``pallas`` when running on TPU, ``ref`` (the XLA-native oracle graph)
-    everywhere else.  ``None``/"auto" defers to env/backend.  The resolved
-    string is what populations bake into their jit caches and pass down the
-    step factories, so the hot path never reads ambient state.
+    Priority: explicit value > backend default — ``pallas`` when running
+    on TPU, ``ref`` (the XLA-native oracle graph) everywhere else.
+    ``None``/"auto" defers to the backend.  Off the TPU,
+    ``REPRO_KERNEL_IMPL`` overrides the default; on the TPU it may only
+    name ``pallas``, and any other value raises instead of silently
+    running the oracle or the interpreter in place of the kernels.  The
+    resolved string is what populations bake into their jit caches and
+    pass down the step factories, so the hot path never reads ambient
+    state.
     """
     if impl and impl != "auto":
         return _check_impl(impl)
-    env = os.environ.get("REPRO_KERNEL_IMPL")
-    if env:
-        return _check_impl(env)
     import jax
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    env = os.environ.get("REPRO_KERNEL_IMPL")
+    if jax.default_backend() == "tpu":
+        if env and env != "pallas":
+            raise ValueError(
+                f"REPRO_KERNEL_IMPL={env!r} would replace the compiled "
+                "Pallas kernels on the TPU; unset it, or pass the impl "
+                "explicitly where a reference run is meant")
+        return "pallas"
+    return _check_impl(env) if env else "ref"
 
 
 # ---------------------------------------------------------------------------

@@ -94,8 +94,20 @@ def _sparse_kl_kernel(live_ref, idx_ref, logp_ref, w_ref, out_ref,
         cross = jnp.sum(p_at * logp[None], axis=-1)      # (Kl, J, bb)
         kl = neg_h[:, None, :] - c[None] * (1.0 - s) - cross
         w = w_ref[...].astype(jnp.float32)               # (Kl, J)
-        out_ref[...] = jnp.sum(kl * w[:, :, None],
-                               axis=1).astype(out_ref.dtype)
+        out_ref[0] = jnp.sum(kl * w[:, :, None],
+                             axis=1).astype(out_ref.dtype)
+
+
+# bytes the kernel's (bb, k, bv) fp32 one-hot gather operand may take in
+# VMEM (the TPU's scoped VMEM limit is 16 MiB; the live block, its exp
+# and the double-buffered inputs share it)
+_GATHER_VMEM_BYTES = 4 * 2 ** 20
+
+
+def _gather_block_v(bb: int, k: int) -> int:
+    """Largest vocab block (a multiple of 128) whose one-hot gather
+    operand fits ``_GATHER_VMEM_BYTES``."""
+    return max(128, _GATHER_VMEM_BYTES // (bb * k * 4) // 128 * 128)
 
 
 def _sparse_kl_forward(live, idx, logp_top, pair_w, temperature: float,
@@ -103,7 +115,7 @@ def _sparse_kl_forward(live, idx, logp_top, pair_w, temperature: float,
     Kl, B, V = live.shape
     J, _, k = idx.shape
     bb = min(block_b, B)
-    bv = min(block_v, V)
+    bv = min(block_v, V, _gather_block_v(bb, k))
     pad_b = (-B) % bb
     pad_v = (-V) % bv
     if pad_b or pad_v:
@@ -126,8 +138,11 @@ def _sparse_kl_forward(live, idx, logp_top, pair_w, temperature: float,
                   pl.BlockSpec((J, bb, k), lambda ib, iv: (0, ib, 0)),
                   pl.BlockSpec((J, bb, k), lambda ib, iv: (0, ib, 0)),
                   pl.BlockSpec((Kl, J), lambda ib, iv: (0, 0))],
-        out_specs=pl.BlockSpec((Kl, bb), lambda ib, iv: (0, ib)),
-        out_shape=jax.ShapeDtypeStruct((Kl, Bp), jnp.float32),
+        # one (Kl, bb) tile per batch block, on a leading block axis: a
+        # (Kl, bb) window of a (Kl, Bp) array needs bb % 128 == 0 on the
+        # TPU, and bb = 128 would double the (bb, k, bv) gather operand
+        out_specs=pl.BlockSpec((1, Kl, bb), lambda ib, iv: (ib, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_b, Kl, bb), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((Kl, bb), jnp.float32),           # running max m
             pltpu.VMEM((Kl, bb), jnp.float32),           # partition A
@@ -136,7 +151,7 @@ def _sparse_kl_forward(live, idx, logp_top, pair_w, temperature: float,
         ],
         interpret=interpret,
     )(live, idx, logp_top, pair_w)
-    return out[:, :B]
+    return out.transpose(1, 0, 2).reshape(Kl, Bp)[:, :B]
 
 
 def _streaming_lse_entropy(blocks):

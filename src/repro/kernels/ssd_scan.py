@@ -32,8 +32,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
-                states_in_ref, state_ref, *, L: int, n_chunks: int):
+def _ssd_kernel(x_ref, dt_ref, dtr_ref, a_ref, b_ref, c_ref, y_ref,
+                state_out_ref, states_in_ref, state_ref, *, L: int,
+                n_chunks: int):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
@@ -45,32 +46,40 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
     states_in_ref[0, 0, 0] = state_ref[...]
 
     x = x_ref[0, 0, 0].astype(jnp.float32)               # (L, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)             # (L,)... stored (L,1)
-    dt = dt[:, 0]
-    a = a_ref[0, 0].astype(jnp.float32)                  # scalar
+    # dt arrives as a column (L, 1) and as a row (1, L): the TPU has no
+    # in-kernel cumsum or 1-D vectors, so every per-token quantity stays
+    # 2-D and the cumsums are triangular matmuls in both orientations
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)             # (L, 1)
+    dt_row = dtr_ref[0, 0, 0].astype(jnp.float32)        # (1, L)
+    a = a_ref[pl.program_id(1), 0]                       # scalar (SMEM)
     bmat = b_ref[0, 0, 0].astype(jnp.float32)            # (L, N)
     cmat = c_ref[0, 0, 0].astype(jnp.float32)            # (L, N)
 
-    da = dt * a                                          # (L,)  <= 0
-    cs = jnp.cumsum(da)                                  # (L,)
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    tri = (row >= col).astype(jnp.float32)               # lower incl. diag
+    hi = jax.lax.Precision.HIGHEST
+    cs = jnp.dot(tri, dt * a, precision=hi)              # (L, 1) cumsum
+    cs_row = jax.lax.dot_general(dt_row * a, tri, (((1,), (1,)), ((), ())),
+                                 precision=hi)           # (1, L)
+    cs_last = jnp.sum(dt_row * a, axis=1, keepdims=True)  # (1, 1)
 
     # intra-chunk
     scores = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())))  # (L,L)
     # clamp the (masked) upper triangle before exp: inf * 0 would be NaN
-    decay = jnp.exp(jnp.minimum(cs[:, None] - cs[None, :], 0.0))
-    tri = jnp.tril(jnp.ones((L, L), jnp.float32))
-    w = scores * decay * dt[None, :] * tri
+    decay = jnp.exp(jnp.minimum(cs - cs_row, 0.0))
+    w = scores * decay * dt_row * tri
     y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())))            # (L,P)
 
     # inter-chunk
     state = state_ref[...]                               # (P, N)
-    y += jnp.exp(cs)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cs) * jax.lax.dot_general(
         cmat, state, (((1,), (1,)), ((), ())))           # (L,N)x(P,N)^T
 
     # state update
-    tail = jnp.exp(cs[-1] - cs) * dt                     # (L,)
-    state_ref[...] = jnp.exp(cs[-1]) * state + jax.lax.dot_general(
-        x, bmat * tail[:, None], (((0,), (0,)), ((), ())))  # (P, N)
+    tail = jnp.exp(cs_last - cs) * dt                    # (L, 1)
+    state_ref[...] = jnp.exp(cs_last) * state + jax.lax.dot_general(
+        x, bmat * tail, (((0,), (0,)), ((), ())))        # (P, N)
 
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
@@ -94,10 +103,10 @@ def _ssd_forward(x, dt, A, B_mat, C_mat, chunk: int, interpret: bool):
 
     # pre-chunk to (B, H, nc, L, ...) / (B, G, nc, L, N)
     xc = x.reshape(Bb, nc, L, H, Pd).transpose(0, 3, 1, 2, 4)
-    dtc = dt.reshape(Bb, nc, L, H).transpose(0, 3, 1, 2)[..., None]  # (B,H,nc,L,1)
+    dtc = dt.reshape(Bb, nc, L, H).transpose(0, 3, 1, 2)    # (B,H,nc,L)
     bc = B_mat.reshape(Bb, nc, L, G, N).transpose(0, 3, 1, 2, 4)
     cc = C_mat.reshape(Bb, nc, L, G, N).transpose(0, 3, 1, 2, 4)
-    a2 = A.reshape(H, 1)
+    a_col = A.astype(jnp.float32).reshape(H, 1)
 
     kernel = functools.partial(_ssd_kernel, L=L, n_chunks=nc)
     y, state, states_in = pl.pallas_call(
@@ -106,7 +115,11 @@ def _ssd_forward(x, dt, A, B_mat, C_mat, chunk: int, interpret: bool):
         in_specs=[
             pl.BlockSpec((1, 1, 1, L, Pd), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, L, 1), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (h, 0)),
+            pl.BlockSpec((1, 1, 1, 1, L), lambda b, h, c: (b, h, c, 0, 0)),
+            # the whole (H, 1) decay column as scalars: a one-head window
+            # of it is below the TPU's (8, 128) tile, and a whole (H,)
+            # vector stops being whole once the client vmap batches it
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, L, N),
                          lambda b, h, c, r=rep: (b, h // r, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, L, N),
@@ -124,7 +137,7 @@ def _ssd_forward(x, dt, A, B_mat, C_mat, chunk: int, interpret: bool):
         ],
         scratch_shapes=[pltpu.VMEM((Pd, N), jnp.float32)],
         interpret=interpret,
-    )(xc, dtc, a2, bc, cc)
+    )(xc, dtc[..., None], dtc[..., None, :], a_col, bc, cc)
     y = y.transpose(0, 2, 3, 1, 4).reshape(Bb, Sp, H, Pd)[:, :S]
     return y, state, states_in
 

@@ -295,7 +295,7 @@ def _costs(compiled) -> Dict[str, float]:
 
 
 def _lower_compile(step, args, in_shardings, mesh):
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=in_shardings).lower(*args)
         return lowered.compile()
 

@@ -214,7 +214,8 @@ def main(argv=None) -> int:
                              "xla_flash"],
                     help="kernel implementation for the hot path: 'auto' "
                          "resolves per backend (pallas on TPU, ref "
-                         "elsewhere; REPRO_KERNEL_IMPL overrides)")
+                         "elsewhere; REPRO_KERNEL_IMPL overrides off "
+                         "the TPU only)")
     ap.add_argument("--save", default=None, help="checkpoint path")
     ap.add_argument("--mesh", default=None, metavar="clients=N",
                     help="device-shard the DML client axis over a "
@@ -284,4 +285,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

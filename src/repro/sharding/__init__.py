@@ -106,62 +106,26 @@ def logical_to_spec(logical: Tuple[Optional[str], ...], mesh=None,
 
 
 def current_mesh():
-    """The ambient (abstract) mesh, or None when unsharded.
-
-    jax >= 0.5 exposes ``jax.sharding.get_abstract_mesh``; on older releases
-    the same state lives in ``jax._src.mesh`` (where the getter may return a
-    bare context tuple instead of a mesh) with the physical mesh stack as a
-    further fallback.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        mesh = getter()
-    else:
-        from jax._src import mesh as _mesh_lib
-        mesh = getattr(_mesh_lib, "get_abstract_mesh", lambda: None)()
-        if not hasattr(mesh, "axis_names"):
-            mesh = _mesh_lib.thread_resources.env.physical_mesh
-    if mesh is None or not hasattr(mesh, "axis_names"):
-        return None
+    """The ambient (abstract) mesh, or None when unsharded."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or not mesh.axis_names:
         return None
     return mesh
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """Uncheck-replicated shard_map across jax versions (check_vma on >= 0.6,
-    check_rep + experimental namespace before)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` without the varying-manual-axes check: the round
+    engines' bodies mix per-device and replicated values by design."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes, axis_names, devices=None):
-    """jax.make_mesh with Auto axis types where the version supports them."""
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                                 axis_types=(axis_type,) * len(axis_names))
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    jax >= 0.6 spells this ``jax.set_mesh``; before that the Mesh object is
-    itself the context manager.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis of type Auto: what no constraint
+    pins is sharded by the compiler's propagation."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def constrain(x: jax.Array, *logical: Optional[str]) -> jax.Array:

@@ -31,7 +31,7 @@ def main():
              if method in ("dml", "mutual", "fedavg_sync") else {})
     with shd.axis_rules(rules):
         step, args, shards = DR.build_case(cfg, shape, mesh, method)
-        with shd.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=shards).lower(*args)
             compiled = lowered.compile()
     stats = DR.collective_stats(compiled.as_text(), pod_stride=4)

@@ -457,10 +457,11 @@ def test_lm_population_prefix_arch(lm_cfg):
 
 
 def test_lm_population_mesh_rejects_non_dense(lm_cfg):
-    class FakeMesh:
-        axis_names = ("clients",)
+    # a one-device clients mesh: the population places its fleet on the
+    # mesh at construction, so the mesh has to be real
+    from repro.launch.mesh import make_client_mesh
     pop = LMClients(lm_cfg, n_clients=2, rounds=1, batch=2, seq=16,
-                    mesh=FakeMesh())
+                    mesh=make_client_mesh(1))
     with pytest.raises(ValueError, match="dense dml"):
         Federation(pop, SparseDML(k=8))
 

@@ -202,6 +202,28 @@ def test_unknown_impl_raises_at_every_entry_point():
             call()
 
 
+@pytest.mark.parametrize("env,expect", [
+    (None, "pallas"), ("pallas", "pallas"), ("ref", ValueError),
+    ("interpret", ValueError), ("xla_flash", ValueError)])
+def test_resolve_impl_env_cannot_replace_pallas_on_tpu(monkeypatch, env,
+                                                       expect):
+    """On a TPU backend ``auto`` is always the compiled kernels: an env
+    override naming anything else is an error, never a silent fallback;
+    an explicit impl argument still wins."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if env is None:
+        monkeypatch.delenv("REPRO_KERNEL_IMPL", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_KERNEL_IMPL", env)
+    if expect is ValueError:
+        with pytest.raises(ValueError, match="REPRO_KERNEL_IMPL"):
+            ops.resolve_impl("auto")
+    else:
+        assert ops.resolve_impl("auto") == expect
+    assert ops.resolve_impl("ref") == "ref"
+
+
 def test_local_train_step_differentiable_under_interpret():
     """make_local_train_step(impl='interpret') differentiates straight
     through the attention/SSD Pallas kernels (their custom VJPs; formerly

@@ -171,6 +171,39 @@ def test_sharded_llm_dml_step_matches_unsharded():
         np.testing.assert_array_equal(np.asarray(a)[1], np.asarray(b)[1])
 
 
+@pytest.mark.parametrize("K", [4, 8, 5])
+def test_lm_population_mesh_placement_and_parity(K):
+    """LMClients(mesh=...) builds the fleet where the sharded step keeps
+    it (one block of K/4 clients per device; replicated for an uneven K),
+    keeps it there across rounds, and its round-0 metrics match the
+    unsharded population's."""
+    from repro.api import DML, Federation, LMClients
+    from repro.configs import get_reduced
+    from repro.core import distributed as dml
+    mesh = _mesh(4)
+    cfg = get_reduced("qwen3-4b")
+
+    def run(m):
+        fed = Federation(LMClients(cfg, n_clients=K, rounds=2, batch=2,
+                                   seq=16, seed=1, mesh=m), DML())
+        return fed, jax.tree.leaves(fed.population.client_params)[0]
+
+    def held(leaf):
+        return sorted(s.data.shape[0] for s in leaf.addressable_shards)
+
+    (a, _), (b, leaf) = run(None), run(mesh)
+    k_loc, _ = dml.sharded_client_layout(K, 4)
+    want = [k_loc] * 4 if K % 4 == 0 else [K] * 4
+    assert held(leaf) == want
+    a.run()
+    b.run()
+    assert held(jax.tree.leaves(b.population.client_params)[0]) == want
+    ra, rb = a.history.rounds[0], b.history.rounds[0]
+    np.testing.assert_allclose(ra.client_loss, rb.client_loss, atol=1e-5)
+    np.testing.assert_allclose(ra.kl_loss, rb.kl_loss, atol=1e-5)
+    np.testing.assert_allclose(ra.public_ce, rb.public_ce, atol=1e-5)
+
+
 def test_federation_mesh_bitwise_parity():
     """The unified API composes the execution backend too: a directly-built
     Federation(VisionClients(mesh=...), DML()) matches the single-device
