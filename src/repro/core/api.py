@@ -36,6 +36,8 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from repro import checkpoint
 from repro.data.federated import sample_participants
 
@@ -106,7 +108,10 @@ class Federation:
         second ``run()`` continue exactly where the checkpoint left off."""
         stop = until or self.rounds
         for r in range(self.round, min(stop, self.rounds)):
-            self._run_round(r)
+            # the profiler's span of the whole round; the population's
+            # spans inside it carry the same ``round`` stat (docs/API.md)
+            with jax.profiler.TraceAnnotation("federated round", round=r):
+                self._run_round(r)
         return self.history
 
     def _run_round(self, r: int) -> None:

@@ -92,12 +92,7 @@ def make_local_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
     def step(stacked_params, opt_state, tokens, prefix=None,
              part_mask=None):
         def total_loss(sp):
-            if prefix is None:
-                losses, metrics = _cvmap(spmd_axis_name=spmd_client_axis)(
-                    lambda p, t: tfm.loss_fn(p, cfg, t, remat=remat,
-                                             unroll=unroll, impl=impl)
-                )(sp, tokens)
-            else:
+            with jax.named_scope("private_loss"):
                 losses, metrics = _cvmap(spmd_axis_name=spmd_client_axis)(
                     lambda p, t, pe: tfm.loss_fn(p, cfg, t, pe, remat=remat,
                                                  unroll=unroll, impl=impl)
@@ -107,11 +102,8 @@ def make_local_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
             return jnp.sum(losses * pm), metrics
         (_, metrics), grads = jax.value_and_grad(total_loss, has_aux=True)(
             stacked_params)
-        new_params, new_opt, om = adamw_update(stacked_params, grads,
-                                               opt_state, opt_cfg)
-        if part_mask is not None:
-            new_params, new_opt = _mask_participation(
-                stacked_params, opt_state, new_params, new_opt, part_mask)
+        new_params, new_opt, om = _optimizer_update(
+            stacked_params, opt_state, grads, opt_cfg, part_mask)
         return new_params, new_opt, {**metrics, **om}
     return step
 
@@ -121,15 +113,18 @@ def _mutual_term(flat, temperature, sparse_k, part_mask=None, impl=None):
 
     ``impl`` routes both variants through the fused streaming kernels
     (``ops.mutual_kl_pair`` / ``ops.sparse_mutual_kl``) on kernel impls.
+    Its operations, and their backward, carry the ``eq2`` scope.
     """
-    if sparse_k:
-        assert part_mask is None, \
-            "sparse top-k sharing + partial participation not supported yet"
-        idx, logp_top = topk_predictions(
-            jax.lax.stop_gradient(flat), sparse_k, temperature)
-        return sparse_mutual_kl_loss(flat, idx, logp_top, temperature,
-                                     impl=impl)
-    return mutual_kl_loss(flat, temperature, part_mask=part_mask, impl=impl)
+    with jax.named_scope("eq2"):
+        if sparse_k:
+            assert part_mask is None, \
+                "sparse top-k sharing + partial participation not supported yet"
+            idx, logp_top = topk_predictions(
+                jax.lax.stop_gradient(flat), sparse_k, temperature)
+            return sparse_mutual_kl_loss(flat, idx, logp_top, temperature,
+                                         impl=impl)
+        return mutual_kl_loss(flat, temperature, part_mask=part_mask,
+                              impl=impl)
 
 
 def make_mutual_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
@@ -150,18 +145,9 @@ def make_mutual_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
     def step(stacked_params, opt_state, public_tokens, public_prefix=None,
              part_mask=None):
         def total_loss(sp):
-            if public_prefix is None:
-                losses, fwd = _cvmap(spmd_axis_name=spmd_client_axis)(
-                    lambda p: _public_ce_and_logits(p, cfg, public_tokens,
-                                                    None, remat, unroll,
-                                                    impl))(sp)
-            else:
-                losses, fwd = _cvmap(spmd_axis_name=spmd_client_axis)(
-                    lambda p: _public_ce_and_logits(p, cfg, public_tokens,
-                                                    public_prefix, remat,
-                                                    unroll, impl))(sp)
-            K, B, S, V = fwd.shape
-            flat = constrain(fwd.reshape(K, B * S, V), "client", None, "vocab")
+            losses, flat = _public_forward(
+                sp, cfg, public_tokens, public_prefix, remat, unroll, impl,
+                spmd_client_axis)
             kl = _mutual_term(flat, temperature, sparse_k, part_mask,
                               impl=impl)  # (K,)
             pm = 1.0 if part_mask is None else jnp.asarray(part_mask,
@@ -171,13 +157,23 @@ def make_mutual_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
             return total, {"public_ce": losses, "kld_avg": kl}
         (_, metrics), grads = jax.value_and_grad(total_loss, has_aux=True)(
             stacked_params)
-        new_params, new_opt, om = adamw_update(stacked_params, grads,
-                                               opt_state, opt_cfg)
-        if part_mask is not None:
-            new_params, new_opt = _mask_participation(
-                stacked_params, opt_state, new_params, new_opt, part_mask)
+        new_params, new_opt, om = _optimizer_update(
+            stacked_params, opt_state, grads, opt_cfg, part_mask)
         return new_params, new_opt, {**metrics, **om}
     return step
+
+
+def _public_forward(stacked_params, cfg, tokens, prefix, remat, unroll,
+                    impl, spmd_client_axis):
+    """Every client's CE on the shared public batch and its public logits
+    flattened to (K, B_pub * S, V), under the ``public_logits`` scope."""
+    with jax.named_scope("public_logits"):
+        losses, fwd = _cvmap(spmd_axis_name=spmd_client_axis)(
+            lambda p: _public_ce_and_logits(p, cfg, tokens, prefix, remat,
+                                            unroll, impl))(stacked_params)
+        K, B, S, V = fwd.shape
+        flat = constrain(fwd.reshape(K, B * S, V), "client", None, "vocab")
+    return losses, flat
 
 
 def _public_ce_and_logits(params, cfg, tokens, prefix, remat, unroll=False,
@@ -193,6 +189,18 @@ def _public_ce_and_logits(params, cfg, tokens, prefix, remat, unroll=False,
     ce = -jnp.mean(jnp.take_along_axis(logp, labels[..., None], -1))
     # mutual KL acts on the token-position logits (prefix stripped)
     return ce, logits[:, P:] if P else logits
+
+
+def _optimizer_update(params, opt_state, grads, opt_cfg, part_mask):
+    """AdamW with its clip, under the ``optimizer`` scope; with
+    ``part_mask``, absent clients keep their state."""
+    with jax.named_scope("optimizer"):
+        new_params, new_opt, om = adamw_update(params, grads, opt_state,
+                                               opt_cfg)
+        if part_mask is not None:
+            new_params, new_opt = _mask_participation(
+                params, opt_state, new_params, new_opt, part_mask)
+    return new_params, new_opt, om
 
 
 def _mask_participation(old_params, old_opt, new_params, new_opt, part_mask):
@@ -221,26 +229,14 @@ def make_dml_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
     def step(stacked_params, opt_state, tokens, public_tokens,
              prefix=None, public_prefix=None, part_mask=None):
         def total_loss(sp):
-            if prefix is None:
-                priv, pm = _cvmap(spmd_axis_name=spmd_client_axis)(
-                    lambda p, t: tfm.loss_fn(p, cfg, t, remat=remat,
-                                             unroll=unroll, impl=impl)
-                )(sp, tokens)
-                ce_pub, fwd = _cvmap(spmd_axis_name=spmd_client_axis)(
-                    lambda p: _public_ce_and_logits(p, cfg, public_tokens,
-                                                    None, remat, unroll,
-                                                    impl))(sp)
-            else:
-                priv, pm = _cvmap(spmd_axis_name=spmd_client_axis)(
+            with jax.named_scope("private_loss"):
+                priv, _ = _cvmap(spmd_axis_name=spmd_client_axis)(
                     lambda p, t, pe: tfm.loss_fn(p, cfg, t, pe, remat=remat,
                                                  unroll=unroll, impl=impl)
                 )(sp, tokens, prefix)
-                ce_pub, fwd = _cvmap(spmd_axis_name=spmd_client_axis)(
-                    lambda p: _public_ce_and_logits(p, cfg, public_tokens,
-                                                    public_prefix, remat,
-                                                    unroll, impl))(sp)
-            K, B, S, V = fwd.shape
-            flat = constrain(fwd.reshape(K, B * S, V), "client", None, "vocab")
+            ce_pub, flat = _public_forward(
+                sp, cfg, public_tokens, public_prefix, remat, unroll, impl,
+                spmd_client_axis)
             kl = _mutual_term(flat, temperature, sparse_k, part_mask,
                               impl=impl)
             w = 1.0 if part_mask is None else jnp.asarray(part_mask,
@@ -251,11 +247,8 @@ def make_dml_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
                            "kld_avg": kl}
         (_, metrics), grads = jax.value_and_grad(total_loss, has_aux=True)(
             stacked_params)
-        new_params, new_opt, om = adamw_update(stacked_params, grads,
-                                               opt_state, opt_cfg)
-        if part_mask is not None:
-            new_params, new_opt = _mask_participation(
-                stacked_params, opt_state, new_params, new_opt, part_mask)
+        new_params, new_opt, om = _optimizer_update(
+            stacked_params, opt_state, grads, opt_cfg, part_mask)
         return new_params, new_opt, {**metrics, **om}
     return step
 
@@ -306,23 +299,27 @@ def make_sharded_dml_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
         pair_w = jnp.take(_pair_mask(k_pad, pm_full), gids, axis=0)
 
         def total_loss(sp):
-            priv, _ = jax.vmap(
-                lambda p, t: tfm.loss_fn(p, cfg, t, remat=remat,
-                                         unroll=unroll,
-                                         impl=impl))(sp, tokens)
-            ce_pub, fwd = jax.vmap(
-                lambda p: _public_ce_and_logits(p, cfg, public_tokens,
-                                                None, remat, unroll,
-                                                impl))(sp)
-            K_l, B, S, V = fwd.shape
-            flat = fwd.reshape(K_l, B * S, V)
-            # blocked layout: the tiled gather is already in client order
-            gathered = jax.lax.all_gather(
-                jax.lax.stop_gradient(flat), stacking.CLIENT_AXIS, axis=0,
-                tiled=True)
-            kl = jnp.mean(ops.mutual_kl_pair(
-                flat, gathered, pair_w, temperature=temperature,
-                impl=impl), axis=-1)                          # (K_loc,)
+            with jax.named_scope("private_loss"):
+                priv, _ = jax.vmap(
+                    lambda p, t: tfm.loss_fn(p, cfg, t, remat=remat,
+                                             unroll=unroll,
+                                             impl=impl))(sp, tokens)
+            with jax.named_scope("public_logits"):
+                ce_pub, fwd = jax.vmap(
+                    lambda p: _public_ce_and_logits(p, cfg, public_tokens,
+                                                    None, remat, unroll,
+                                                    impl))(sp)
+                K_l, B, S, V = fwd.shape
+                flat = fwd.reshape(K_l, B * S, V)
+            with jax.named_scope("eq2"):
+                # blocked layout: the tiled gather is already in client
+                # order
+                gathered = jax.lax.all_gather(
+                    jax.lax.stop_gradient(flat), stacking.CLIENT_AXIS,
+                    axis=0, tiled=True)
+                kl = jnp.mean(ops.mutual_kl_pair(
+                    flat, gathered, pair_w, temperature=temperature,
+                    impl=impl), axis=-1)                      # (K_loc,)
             total = (jnp.sum(priv * pm_loc) + jnp.sum(ce_pub * pm_loc)
                      + kl_weight * jnp.sum(kl))
             return total, {"private_loss": priv, "public_ce": ce_pub,
@@ -330,15 +327,15 @@ def make_sharded_dml_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
 
         (_, metrics), grads = jax.value_and_grad(total_loss, has_aux=True)(
             params)
-        if opt_cfg.clip_norm is not None:
-            grads, gnorm = jax.vmap(
-                lambda g: clip_by_global_norm(g, opt_cfg.clip_norm))(grads)
-        else:
-            gnorm = jax.vmap(global_norm)(grads)
-        new_params, new_opt, om = adamw_update(params, grads, opt,
-                                               opt_noclip)
-        new_params, new_opt = _mask_participation(params, opt, new_params,
-                                                  new_opt, pm_loc)
+        with jax.named_scope("optimizer"):
+            if opt_cfg.clip_norm is not None:
+                grads, gnorm = jax.vmap(
+                    lambda g: clip_by_global_norm(g, opt_cfg.clip_norm))(
+                        grads)
+            else:
+                gnorm = jax.vmap(global_norm)(grads)
+        new_params, new_opt, om = _optimizer_update(params, opt, grads,
+                                                    opt_noclip, pm_loc)
         return new_params, new_opt, {**metrics, "grad_norm": gnorm,
                                      "lr": om["lr"]}
 

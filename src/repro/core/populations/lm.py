@@ -15,6 +15,11 @@ K same-arch clients live as a leading axis on every param/opt leaf
 Private data is per-client synthetic bigram streams (one domain per
 client — non-IID); the public batch is fresh every round ("dynamically
 changing test dataset", paper §III.A).
+
+Under ``jax.profiler.trace`` a round writes the host spans ``batch
+build``, ``dispatch`` and ``metrics sync``, each with the stat ``round``;
+``compiled_programs`` counts the programs the round steps compiled
+(docs/API.md, "Profiling a federation").
 """
 from __future__ import annotations
 
@@ -76,6 +81,7 @@ class LMClients(Population):
                 init, out_shardings=(sh, {"mu": sh, "nu": sh, "step": rep})
             )(key)
         self._steps = {}
+        self._jitted = []      # every jax.jit this population made
         self._last_metrics = {}
 
     def validate_strategy(self, strategy) -> None:
@@ -92,18 +98,25 @@ class LMClients(Population):
     # -- data -------------------------------------------------------------
     def _private_batch(self, r: int):
         """(K, B, S) tokens — each client has its own bigram domain."""
-        return jnp.stack([
-            jnp.asarray(make_token_stream(
-                self.batch, self.seq + 1, self.cfg.vocab_size,
-                seed=1000 * r + self.seed, domain=d)[:, :self.seq])
-            for d in range(self.n_clients)])
+        with jax.profiler.TraceAnnotation(
+                "batch build", round=r, which="private",
+                tokens=self.n_clients * self.batch * self.seq):
+            return jnp.stack([
+                jnp.asarray(make_token_stream(
+                    self.batch, self.seq + 1, self.cfg.vocab_size,
+                    seed=1000 * r + self.seed, domain=d)[:, :self.seq])
+                for d in range(self.n_clients)])
 
     def _public_batch(self, r: int):
         """(B_pub, S) fresh public tokens from an unseen domain."""
-        return jnp.asarray(make_token_stream(
-            max(1, self.batch // 2), self.seq + 1, self.cfg.vocab_size,
-            seed=1000 * (10_000 + r) + self.seed,
-            domain=self.n_clients)[:, :self.seq])
+        b_pub = max(1, self.batch // 2)
+        with jax.profiler.TraceAnnotation(
+                "batch build", round=r, which="public",
+                tokens=b_pub * self.seq):
+            return jnp.asarray(make_token_stream(
+                b_pub, self.seq + 1, self.cfg.vocab_size,
+                seed=1000 * (10_000 + r) + self.seed,
+                domain=self.n_clients)[:, :self.seq])
 
     def _prefix(self, r: int, batch: int):
         """(B, P, pd) conditioning embeddings for modality-frontend archs
@@ -122,15 +135,30 @@ class LMClients(Population):
         return jnp.broadcast_to(p[None], (self.n_clients,) + p.shape)
 
     # -- cached jitted steps ----------------------------------------------
+    def _jit(self, fn):
+        step = jax.jit(fn)
+        self._jitted.append(step)
+        return step
+
+    @property
+    def compiled_programs(self) -> int:
+        """Programs compiled so far for this population's jitted steps:
+        one per step and argument signature (shapes, dtypes, placement).
+        It stays put over the rounds of a federation unless a round needs
+        a new program."""
+        # counted on the population's own list: ``_steps`` values may be
+        # wrapped from outside
+        return sum(step._cache_size() for step in self._jitted)
+
     def _dml_step(self, kl_weight: float, sparse_k: int):
         key = ("dml", kl_weight, sparse_k, self.mesh is not None, self.impl)
         if key not in self._steps:
             if self.mesh is not None:
-                self._steps[key] = jax.jit(D.make_sharded_dml_step(
+                self._steps[key] = self._jit(D.make_sharded_dml_step(
                     self.cfg, self.opt_cfg, self.mesh, self.n_clients,
                     kl_weight=kl_weight, impl=self.impl))
             else:
-                self._steps[key] = jax.jit(D.make_dml_train_step(
+                self._steps[key] = self._jit(D.make_dml_train_step(
                     self.cfg, self.opt_cfg, kl_weight=kl_weight,
                     sparse_k=sparse_k, impl=self.impl))
         return self._steps[key]
@@ -138,19 +166,31 @@ class LMClients(Population):
     def _local_step(self):
         key = ("local", self.impl)
         if key not in self._steps:
-            self._steps[key] = jax.jit(D.make_local_train_step(
+            self._steps[key] = self._jit(D.make_local_train_step(
                 self.cfg, self.opt_cfg, impl=self.impl))
         return self._steps[key]
+
+    def _dispatch(self, r: int, step, *args, **kwargs):
+        """Call a round step inside the ``dispatch`` span: it returns once
+        the program is enqueued (or compiled, on a new shape)."""
+        with jax.profiler.TraceAnnotation("dispatch", round=r) as span:
+            out = step(*args, **kwargs)
+            if jax.profiler.TraceAnnotation.is_enabled():
+                span.set_metadata(programs=self.compiled_programs)
+        return out
 
     # -- strategy capabilities --------------------------------------------
     def local_phase(self, r: int, part: List[int], pm) -> List[float]:
         part_mask = jnp.asarray(pm) if len(part) < self.n_clients else None
         tokens = self._private_batch(r)
-        self.client_params, self.client_opts, m = self._local_step()(
-            self.client_params, self.client_opts, tokens,
-            self._private_prefix(r), part_mask)
+        prefix = self._private_prefix(r)
+        self.client_params, self.client_opts, m = self._dispatch(
+            r, self._local_step(), self.client_params, self.client_opts,
+            tokens, prefix, part_mask)
         self._last_metrics = m
-        return [float(x) * w for x, w in zip(np.asarray(m["ce"]), pm)]
+        # the first read of the round's results waits for the program
+        with jax.profiler.TraceAnnotation("metrics sync", round=r):
+            return [float(x) * w for x, w in zip(np.asarray(m["ce"]), pm)]
 
     def public_payload(self, r: int):
         return self._public_batch(r)
@@ -173,24 +213,22 @@ class LMClients(Population):
         part_mask = jnp.asarray(pm) if len(part) < self.n_clients else None
         tokens = self._private_batch(r)
         step = self._dml_step(kl_weight, sparse_k)
-        if self.mesh is not None:
-            self.client_params, self.client_opts, m = step(
-                self.client_params, self.client_opts, tokens, pub,
-                part_mask=part_mask)
-        else:
-            self.client_params, self.client_opts, m = step(
-                self.client_params, self.client_opts, tokens, pub,
-                prefix=self._private_prefix(r),
-                public_prefix=self._prefix(10_000 + r,
-                                           int(pub.shape[0])),
-                part_mask=part_mask)
+        prefixes = {} if self.mesh is not None else {
+            "prefix": self._private_prefix(r),
+            "public_prefix": self._prefix(10_000 + r, int(pub.shape[0]))}
+        self.client_params, self.client_opts, m = self._dispatch(
+            r, step, self.client_params, self.client_opts, tokens, pub,
+            part_mask=part_mask, **prefixes)
         self._last_metrics = m
-        return {"ran": len(part) >= 2,
-                "positions": int(pub.shape[0]) * int(pub.shape[1]),
-                "client_loss": [float(x) for x in
-                                np.asarray(m["private_loss"])],
-                "public_ce": [float(x) for x in np.asarray(m["public_ce"])],
-                "kl_loss": [float(x) for x in np.asarray(m["kld_avg"])]}
+        # the first read of the round's results waits for the program
+        with jax.profiler.TraceAnnotation("metrics sync", round=r):
+            return {"ran": len(part) >= 2,
+                    "positions": int(pub.shape[0]) * int(pub.shape[1]),
+                    "client_loss": [float(x) for x in
+                                    np.asarray(m["private_loss"])],
+                    "public_ce": [float(x) for x in
+                                  np.asarray(m["public_ce"])],
+                    "kl_loss": [float(x) for x in np.asarray(m["kld_avg"])]}
 
     def fedavg_combine(self, part: List[int], pm) -> None:
         full = len(part) == self.n_clients
@@ -241,7 +279,7 @@ class LMClients(Population):
             self.batch, self.seq + 1, self.cfg.vocab_size,
             seed=777_000 + self.seed, domain=self.n_clients)[:, :self.seq])
         if "eval" not in self._steps:
-            self._steps["eval"] = jax.jit(jax.vmap(
+            self._steps["eval"] = self._jit(jax.vmap(
                 lambda p, t, pe: tfm.loss_fn(p, self.cfg, t, pe,
                                              impl=self.impl)[0],
                 in_axes=(0, None, None)))

@@ -137,6 +137,7 @@ def _flash_forward(q, k, v, causal: bool, window: Optional[int],
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v)
     return out[:, :, :S], lse[:, :, :S, 0]

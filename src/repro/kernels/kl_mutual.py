@@ -166,6 +166,7 @@ def _kl_pair_forward(live, fixed, pair_w, temperature: float,
             pltpu.VMEM((Kg, bb), jnp.float32),
             pltpu.VMEM((Kl, Kg, bb), jnp.float32),
         ],
+        name="kl_mutual_pair",
         interpret=interpret,
     )(live, fixed, pair_w)
     return out[:, :B]
@@ -314,6 +315,7 @@ def kl_mutual(logits, *, temperature: float = 1.0,
             pltpu.VMEM((K, bb), jnp.float32),
             pltpu.VMEM((K, K, bb), jnp.float32),
         ],
+        name="kl_mutual",
         interpret=interpret,
     )(logits)
     return out[:, :B]

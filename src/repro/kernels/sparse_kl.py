@@ -149,6 +149,7 @@ def _sparse_kl_forward(live, idx, logp_top, pair_w, temperature: float,
             pltpu.VMEM((Kl, bb), jnp.float32),           # entropy acc U
             pltpu.VMEM((Kl, J, bb, k), jnp.float32),     # gathered logits
         ],
+        name="sparse_kl_topk",
         interpret=interpret,
     )(live, idx, logp_top, pair_w)
     return out.transpose(1, 0, 2).reshape(Kl, Bp)[:, :B]

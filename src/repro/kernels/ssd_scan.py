@@ -136,6 +136,7 @@ def _ssd_forward(x, dt, A, B_mat, C_mat, chunk: int, interpret: bool):
             jax.ShapeDtypeStruct((Bb, H, nc, Pd, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((Pd, N), jnp.float32)],
+        name="ssd_scan_fwd",
         interpret=interpret,
     )(xc, dtc[..., None], dtc[..., None, :], a_col, bc, cc)
     y = y.transpose(0, 2, 3, 1, 4).reshape(Bb, Sp, H, Pd)[:, :S]
