@@ -16,6 +16,14 @@ Private data is per-client synthetic bigram streams (one domain per
 client — non-IID); the public batch is fresh every round ("dynamically
 changing test dataset", paper §III.A).
 
+The host builds round r+1's token batches while the chip runs round r:
+after a round's program is enqueued and before its metrics are read back,
+the batches the round used are built for the next round and held; the
+next round takes them ready-made (``batches_ahead``) or, when none is
+held for it, builds them then (``batches_on_demand``).  The rows depend
+only on the round, the seed and the shapes, so where a batch is built
+changes when the host works, never what the round trains on.
+
 Under ``jax.profiler.trace`` a round writes the host spans ``batch
 build``, ``dispatch`` and ``metrics sync``, each with the stat ``round``;
 ``compiled_programs`` counts the programs the round steps compiled
@@ -83,6 +91,12 @@ class LMClients(Population):
         self._steps = {}
         self._jitted = []      # every jax.jit this population made
         self._last_metrics = {}
+        # (round, "private" | "public") -> batch built during an earlier
+        # round's program; at most one round's batches are held
+        self._held = {}
+        self._used = {}        # kind -> the last round that looked it up
+        self.batches_ahead = 0
+        self.batches_on_demand = 0
 
     def validate_strategy(self, strategy) -> None:
         super().validate_strategy(strategy)
@@ -98,25 +112,52 @@ class LMClients(Population):
     # -- data -------------------------------------------------------------
     def _private_batch(self, r: int):
         """(K, B, S) tokens — each client has its own bigram domain."""
+        return self._batch(r, "private")
+
+    def _public_batch(self, r: int):
+        """(B_pub, S) fresh public tokens from an unseen domain."""
+        return self._batch(r, "public")
+
+    def _batch(self, r: int, which: str):
+        """Round r's ``which`` batch: the one built ahead, while an
+        earlier round's program ran, if it is held; else built now."""
+        self._used[which] = r
+        batch = self._held.pop((r, which), None)
+        if batch is not None:
+            self.batches_ahead += 1
+            return batch
+        self.batches_on_demand += 1
+        return self._build(r, which, ahead=False)
+
+    def _build(self, r: int, which: str, ahead: bool):
+        """Make round r's ``which`` batch from its seeds, inside the
+        ``batch build`` span (stat ``ahead``: built during an earlier
+        round's program, or on demand)."""
+        b_pub = max(1, self.batch // 2)
+        tokens = (self.n_clients * self.batch if which == "private"
+                  else b_pub) * self.seq
         with jax.profiler.TraceAnnotation(
-                "batch build", round=r, which="private",
-                tokens=self.n_clients * self.batch * self.seq):
+                "batch build", round=r, which=which, tokens=tokens,
+                ahead=int(ahead)):
+            if which == "public":
+                return jnp.asarray(make_token_stream(
+                    b_pub, self.seq + 1, self.cfg.vocab_size,
+                    seed=1000 * (10_000 + r) + self.seed,
+                    domain=self.n_clients)[:, :self.seq])
             return jnp.stack([
                 jnp.asarray(make_token_stream(
                     self.batch, self.seq + 1, self.cfg.vocab_size,
                     seed=1000 * r + self.seed, domain=d)[:, :self.seq])
                 for d in range(self.n_clients)])
 
-    def _public_batch(self, r: int):
-        """(B_pub, S) fresh public tokens from an unseen domain."""
-        b_pub = max(1, self.batch // 2)
-        with jax.profiler.TraceAnnotation(
-                "batch build", round=r, which="public",
-                tokens=b_pub * self.seq):
-            return jnp.asarray(make_token_stream(
-                b_pub, self.seq + 1, self.cfg.vocab_size,
-                seed=1000 * (10_000 + r) + self.seed,
-                domain=self.n_clients)[:, :self.seq])
+    def _build_ahead(self, r: int) -> None:
+        """Build round r+1's batches of the kinds round r used, for the
+        next round to take; called once round r's program is enqueued, so
+        the host builds while the chip runs it."""
+        if r + 1 >= self.rounds:
+            return
+        self._held = {(r + 1, w): self._build(r + 1, w, ahead=True)
+                      for w, used in self._used.items() if used == r}
 
     def _prefix(self, r: int, batch: int):
         """(B, P, pd) conditioning embeddings for modality-frontend archs
@@ -188,6 +229,7 @@ class LMClients(Population):
             r, self._local_step(), self.client_params, self.client_opts,
             tokens, prefix, part_mask)
         self._last_metrics = m
+        self._build_ahead(r)
         # the first read of the round's results waits for the program
         with jax.profiler.TraceAnnotation("metrics sync", round=r):
             return [float(x) * w for x, w in zip(np.asarray(m["ce"]), pm)]
@@ -220,6 +262,7 @@ class LMClients(Population):
             r, step, self.client_params, self.client_opts, tokens, pub,
             part_mask=part_mask, **prefixes)
         self._last_metrics = m
+        self._build_ahead(r)
         # the first read of the round's results waits for the program
         with jax.profiler.TraceAnnotation("metrics sync", round=r):
             return {"ran": len(part) >= 2,
@@ -307,3 +350,4 @@ class LMClients(Population):
     def load_state_dict(self, state: dict, meta: dict) -> None:
         self.client_params = state["client_params"]
         self.client_opts = state["client_opts"]
+        self._held = {}

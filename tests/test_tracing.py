@@ -70,11 +70,27 @@ def test_each_round_writes_its_spans(session, r):
 
 @pytest.mark.parametrize("r", range(TRACED))
 def test_spans_lie_inside_their_round(session, r):
+    """A round's dispatch and metrics sync lie inside its own round.  Its
+    batches are built on demand inside it in round 0, and from round 1
+    on inside the round before, after that round's program was
+    dispatched and before the host reads its metrics back."""
     _, _, events = session
     [(_, lo, hi, _)] = _spans(events, "federated round", r)
-    inner = [e for name in SPANS[1:] for e in _spans(events, name, r)]
-    assert len(inner) == 4
+    inner = [e for name in SPANS[2:] for e in _spans(events, name, r)]
+    assert len(inner) == 2
     assert all(lo <= start and end <= hi for _, start, end, _ in inner)
+    builds = _spans(events, "batch build", r)
+    assert len(builds) == 2
+    if r == 0:
+        assert all(lo <= start and end <= hi for _, start, end, _ in builds)
+        assert [e[3]["ahead"] for e in builds] == [0, 0]
+        return
+    [(_, lo, hi, _)] = _spans(events, "federated round", r - 1)
+    [(_, _, dispatched, _)] = _spans(events, "dispatch", r - 1)
+    [(_, synced, _, _)] = _spans(events, "metrics sync", r - 1)
+    assert all(lo <= dispatched <= start and end <= synced <= hi
+               for _, start, end, _ in builds)
+    assert [e[3]["ahead"] for e in builds] == [1, 1]
 
 
 def test_no_host_event_named_round(session):
