@@ -49,7 +49,7 @@ def main(argv=None) -> int:
     from benchmarks.chip.reference import dml as ref_dml
 
     cell = harness.load_cell(args.workload)
-    harness.chips_for(cell, True)
+    devices = harness.chips_for(cell, True)[:cell.chips]
     harness.use_cache()
     sink = open(args.out, "a") if args.out else None
 
@@ -77,7 +77,8 @@ def main(argv=None) -> int:
         key = (precision, fault)
         if key not in refs:
             refs[key] = ref_dml.Federation(cell.family, cell.config,
-                                           cell.traffic, precision, fault)
+                                           cell.traffic, precision, fault,
+                                           devices)
         return refs[key]
 
     for seed in seeds:

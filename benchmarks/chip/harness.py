@@ -39,6 +39,9 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_ROUNDS = 6          # rounds profiled at the start of a traced window
+# traced rounds before those that the metrics read: the first traced round
+# can hold a host stall (about 200 ms on four chips) that no later round has
+SETTLE_ROUNDS = 1
 GIB = 2 ** 30
 
 
@@ -309,7 +312,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        while r - r0 < TRACE_ROUNDS and time.perf_counter() - t0 < seconds:
+        while (r - r0 < SETTLE_ROUNDS + TRACE_ROUNDS
+               and time.perf_counter() - t0 < seconds):
             with jax.profiler.StepTraceAnnotation("round", step_num=r):
                 fed.run(until=r + 1)
             r += 1
@@ -338,7 +342,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
         tr = T.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         n_traced = len(T.rounds(tr))
-        wins = [T.device_window(d, n_traced) for d in tr.devices[:cell.chips]]
+        wins = [T.device_window(d, n_traced, skip=SETTLE_ROUNDS)
+                for d in tr.devices[:cell.chips]]
         ctx = TraceContext(tr, [w for w in wins if w], cell, peak_table)
         if on_chip and (len(ctx.windows) != cell.chips or
                         ctx.mean_busy_s() <= 0):
@@ -362,8 +367,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
     del fed, pop
     gc.collect()
     t_ref = time.perf_counter()
-    ref = ref_dml.Federation(cell.family, cell.config,
-                             cell.traffic).run(seed, correct.STEPS)
+    ref = ref_dml.Federation(cell.family, cell.config, cell.traffic,
+                             devices=used).run(seed, correct.STEPS)
     values = correct.numbers(prog, ref)
     ok, checks = correct.decide(values, cell.limits)
     if failed:

@@ -16,13 +16,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.api import DML, Federation, LMClients
+from repro.api import DML, Federation, LMClients, SparseDML
 from repro.configs import get_config, get_reduced
 from repro.launch import use_compile_cache  # noqa: F401  (re-exported)
 from repro.launch.mesh import make_client_mesh
 from repro.optim import cosine_schedule
 
-from .reference.dml import client_key
+from .reference.dml import clip_scope, client_key
 
 # the AdamW schedule's length: far past any window, so that it only sets
 # the cosine's horizon (``traffic["optimizer"]["total_steps"]``)
@@ -42,11 +42,16 @@ def strategy(traffic: dict):
     s = traffic["strategy"]
     if s["name"] == "dml":
         return DML(kl_weight=s.get("kl_weight", 1.0))
+    if s["name"] == "sparse-dml":
+        return SparseDML(k=s["k"], kl_weight=s.get("kl_weight", 1.0))
     raise ValueError(f"unknown strategy {s['name']!r}")
 
 
 def optimizer_of(pop) -> dict:
-    """The population's AdamW settings under the traffic file's names."""
+    """The population's AdamW settings under the traffic file's names.
+    Its clip scope is that of the round programs the benchmark runs: on a
+    mesh ``make_sharded_dml_step`` clips each client's gradient to its own
+    norm, without one the fused round clips the whole fleet's to one."""
     o = pop.opt_cfg
     if o.schedule != "cosine":
         raise ValueError(f"the population's schedule is {o.schedule!r}")
@@ -54,7 +59,8 @@ def optimizer_of(pop) -> dict:
     return {"name": "adamw", "lr": o.lr, "warmup": o.warmup,
             "total_steps": o.total_steps, "final_frac": final.default,
             "b1": o.b1, "b2": o.b2, "eps": o.eps,
-            "weight_decay": o.weight_decay, "clip_norm": o.clip_norm}
+            "weight_decay": o.weight_decay, "clip_norm": o.clip_norm,
+            "clip_scope": "per_client" if pop.mesh is not None else "fleet"}
 
 
 def build(config: dict, traffic: dict, seed: int, chips: int):
@@ -66,9 +72,11 @@ def build(config: dict, traffic: dict, seed: int, chips: int):
                     rounds=ROUNDS, batch=traffic["batch"], seq=traffic["seq"],
                     lr=traffic["optimizer"]["lr"], seed=seed, mesh=mesh)
     runs = optimizer_of(pop)
-    if runs != traffic["optimizer"]:
+    states = dict(traffic["optimizer"],
+                  clip_scope=clip_scope(traffic["optimizer"]))
+    if runs != states:
         raise ValueError(f"the population runs the optimizer {runs}, the "
-                         f"traffic file states {traffic['optimizer']}")
+                         f"traffic file states {states}")
     return pop, Federation(pop, strategy(traffic))
 
 

@@ -9,29 +9,49 @@ received as data (no gradient flows into them), descends
     KLD_avg_c = mean over public positions of
                 1/(K-1) sum_{j != c} KL(P_c || P_j)            (paper Eq. 2)
 
-with P the softmax of the logits.  The gradients are clipped to one global
-norm over the whole fleet and fed to AdamW with a linear warm-up then cosine schedule, bias-corrected
-moments, and decoupled weight decay on the matrices only.
+with P the softmax of the logits.  Under the sparse strategy
+(``sparse-dml``, k) client j publishes at each public position only the
+indices of its k most likely classes and their log-probabilities, and
+client c takes P_j to be the distribution it rebuilds from them: the k
+published classes at their probabilities, the rest of the mass spread
+evenly over the other V - k classes.
+
+The gradients are clipped as the traffic file's ``optimizer.clip_scope``
+says: ``fleet`` (the default) to one global norm over the whole fleet,
+``per_client`` each client's to its own norm.  They are fed to AdamW with
+a linear warm-up then cosine schedule, bias-corrected moments, and
+decoupled weight decay on the matrices only.
 
 The client's batches are the workload's bigram streams (``common``):
 client c's private rows of round r come from seed 1000 r + s in domain c,
 the public rows from seed 1000 (10000 + r) + s in domain K.
 
 Everything is float32 at the highest matmul precision; the control runs
-the same code with ``precision="fp8"``.  ``fault`` plants one of the
-faults a federated step can have, so that the comparison can be shown to
-fail on it:
+the same code with ``precision="fp8"``.  Given ``devices``, client c's
+weights, moments, gradient and starting weights live on device c mod n,
+and each published payload is copied to the devices of the clients that
+receive it; the clip's sums of squares are the only numbers that cross
+between clients otherwise, and they are added on the host.
+
+``fault`` plants one of the faults a federated step can have, so that the
+comparison can be shown to fail on it:
 
   "half_batch"   the private CE over the first half of the rows only;
   "no_exchange"  every client receives its own public logits in place of
                  the others' (the payload exchange left out).
+
+Where the sparse Eq. 2 departs from the strategy's definition: a top-k
+that holds all of a position's mass leaves the tail a floor of the
+smallest normal float32 in place of nothing, so that its logarithm stays
+finite; and where classes tie at the k-th place, ``jax.lax.top_k`` takes
+the lower indices.
 
 This module imports nothing of the program under test.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -40,10 +60,20 @@ import numpy as np
 from .common import bigram_stream, log_softmax, next_token_ce, seed_key
 
 FAULTS = (None, "half_batch", "no_exchange")
+CLIP_SCOPES = ("fleet", "per_client")
+STRATEGIES = ("dml", "sparse-dml")
 
 
 def client_key(seed: int, client: int):
     return jax.random.fold_in(seed_key(seed), client)
+
+
+def clip_scope(opt: dict) -> str:
+    """How the traffic file's optimizer clips: ``fleet`` unless it says."""
+    scope = opt.get("clip_scope", "fleet")
+    if scope not in CLIP_SCOPES:
+        raise ValueError(f"unknown clip scope {scope!r}")
+    return scope
 
 
 def private_rows(traffic: dict, vocab: int, seed: int, r: int) -> np.ndarray:
@@ -93,27 +123,65 @@ def kl_dense(live, received, weights):
     return jnp.mean(jnp.sum(weights[:, None] * kl, axis=0))
 
 
+def top_k(logits, k: int):
+    """What a client publishes under the sparse strategy, at each row of
+    ``logits`` (N, V): the indices (N, k) of its k most likely classes and
+    their log-probabilities (N, k)."""
+    logp, idx = jax.lax.top_k(log_softmax(logits), k)
+    return idx, logp
+
+
+def rebuilt(idx, logp, V: int):
+    """The log-distribution (..., V) a receiver rebuilds from a published
+    top-k: the k classes at their log-probabilities, the mass left over
+    spread evenly over the other V - k classes."""
+    k = idx.shape[-1]
+    rest = jnp.maximum(1.0 - jnp.sum(jnp.exp(logp), axis=-1),
+                       np.finfo(np.float32).tiny)
+    tail = jnp.broadcast_to(jnp.log(rest / (V - k))[..., None],
+                            idx.shape[:-1] + (V,))
+    return jnp.put_along_axis(tail, idx, logp, axis=-1, inplace=False)
+
+
+def kl_sparse(live, received, weights):
+    """live (N, V), received top-k sets (idx, logp), each (K, N, k),
+    weights (K,) -> mean_n sum_j w_j KL(softmax(live_n) || rebuilt_jn)."""
+    lp = log_softmax(live)
+    lq = rebuilt(*received, live.shape[-1])
+    kl = jnp.sum(jnp.exp(lp)[None] * (lp[None] - lq), axis=-1)    # (K, N)
+    return jnp.mean(jnp.sum(weights[:, None] * kl, axis=0))
+
+
 class Federation:
-    """The reference federation: K clients of one family, on JAX's
-    default device."""
+    """The reference federation: K clients of one family, on ``devices``
+    (client c on device c mod n), or all on JAX's default device."""
 
     def __init__(self, family, cfg: dict, traffic: dict,
-                 precision: str = "fp32", fault: Optional[str] = None):
+                 precision: str = "fp32", fault: Optional[str] = None,
+                 devices: Sequence = ()):
         if fault not in FAULTS:
             raise ValueError(f"unknown fault {fault!r}")
+        strategy = traffic["strategy"]
+        if strategy["name"] not in STRATEGIES:
+            raise ValueError(f"unknown strategy {strategy['name']!r}")
         self.family, self.cfg, self.traffic = family, cfg, traffic
         self.precision, self.fault = precision, fault
+        self.devices = list(devices)
         self.K = traffic["clients"]
         self.V = family.dims(cfg)["V"]
-        self.kl_weight = traffic["strategy"].get("kl_weight", 1.0)
+        self.kl_weight = strategy.get("kl_weight", 1.0)
         self.opt = traffic["optimizer"]
+        self.clip_scope = clip_scope(self.opt)
+        sparse_k = strategy["k"] if strategy["name"] == "sparse-dml" else 0
+        kl_term = kl_sparse if sparse_k else kl_dense
         fwd = family.forward
 
         def logits(p, toks):
             return fwd(p, cfg, toks, precision)
 
         def publish(p, toks):
-            return logits(p, toks).reshape(-1, self.V)
+            flat = logits(p, toks).reshape(-1, self.V)
+            return top_k(flat, sparse_k) if sparse_k else flat
 
         def loss(p, priv, pub, received, weights):
             if fault == "half_batch":
@@ -121,7 +189,7 @@ class Federation:
             priv_ce = next_token_ce(logits(p, priv), priv)
             pub_logits = logits(p, pub)
             pub_ce = next_token_ce(pub_logits, pub)
-            kl = kl_dense(pub_logits.reshape(-1, self.V), received, weights)
+            kl = kl_term(pub_logits.reshape(-1, self.V), received, weights)
             return priv_ce + pub_ce + self.kl_weight * kl, \
                 jnp.stack([priv_ce, pub_ce, kl])
 
@@ -150,18 +218,39 @@ class Federation:
         self._adamw = jax.jit(adamw)
         self._init = jax.jit(lambda k: jax.tree.map(
             lambda x: x.astype(jnp.float32), family.init(k, cfg)))
+        self._zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p))
         self._change = jax.jit(lambda a, b: leaf_norms(
             jax.tree.map(jnp.subtract, a, b)))
 
+    def _on(self, x, c: int):
+        """``x`` on client c's device (as it is, without devices)."""
+        if not self.devices:
+            return x
+        return jax.device_put(x, self.devices[c % len(self.devices)])
+
     def init(self, seed: int) -> List[dict]:
-        """Every client's float32 weights, from the seed."""
-        return [self._init(client_key(seed, c)) for c in range(self.K)]
+        """Every client's float32 weights, from the seed, on its device."""
+        return [self._init(self._on(client_key(seed, c), c))
+                for c in range(self.K)]
 
     def _received(self, published, c: int):
-        """Client c's view of the round's payloads: every client's (its own
-        weighted 0), or under the fault its own in every slot."""
+        """Client c's view of the round's payloads, on its device: every
+        client's (its own weighted 0), or under the fault its own in
+        every slot."""
         pick = [c] * self.K if self.fault == "no_exchange" else range(self.K)
-        return jnp.stack([published[j] for j in pick])
+        return jax.tree.map(lambda *xs: jnp.stack(xs),
+                            *[self._on(published[j], c) for j in pick])
+
+    def _clip_scales(self, sums: List[float]) -> List[float]:
+        """Each client's clip factor from the clients' gradient sums of
+        squares: one factor from their total, or each from its own."""
+        clip = self.opt["clip_norm"]
+
+        def scale(sq):
+            return min(1.0, clip / max(math.sqrt(sq), 1e-9))
+        if self.clip_scope == "fleet":
+            return [scale(sum(sums))] * self.K
+        return [scale(sq) for sq in sums]
 
     def run(self, seed: int, steps: int) -> Dict[str, object]:
         """The first ``steps`` rounds from the seed.  Returns
@@ -174,8 +263,7 @@ class Federation:
         """
         K, opt = self.K, self.opt
         params = self.init(seed)
-        moments = [(jax.tree.map(jnp.zeros_like, p),
-                    jax.tree.map(jnp.zeros_like, p)) for p in params]
+        moments = [(self._zeros(p), self._zeros(p)) for p in params]
         w_rows = (1.0 - np.eye(K, dtype=np.float32)) / max(K - 1, 1)
         losses, grad_norms = [], None
         for r in range(steps):
@@ -186,14 +274,13 @@ class Federation:
                                self._received(published, c), w_rows[c])
                    for c in range(K)]
             del published
-            sq = sum(float(o[2]) for o in out)
-            scale = min(1.0, opt["clip_norm"] / max(math.sqrt(sq), 1e-9))
+            scales = self._clip_scales([float(o[2]) for o in out])
             t = r + 1
             lr = learning_rate(opt, t)
             norms = []
             for c in range(K):
                 params[c], m, v, gn = self._adamw(
-                    params[c], *moments[c], out[c][0], scale, lr, t)
+                    params[c], *moments[c], out[c][0], scales[c], lr, t)
                 moments[c] = (m, v)
                 norms.append(np.asarray(gn))
             if r == 0:

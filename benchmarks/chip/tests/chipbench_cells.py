@@ -3,7 +3,9 @@
 The model is the registry's ``reduced()`` preset of the cell's
 architecture (float32), the traffic is the cell's own with batch 4,
 sequence 64 and public batch 2, and the limits are the cell's.  Only the
-sizes differ from what the chip runs.
+sizes differ from what the chip runs.  A four-chip cell's program runs
+its sharded round on a ``clients`` mesh of the one CPU device, which then
+holds all of the cell's clients (``on_one_device``).
 
 ``HELD_OUT`` are cells whose configuration and reference stay under the
 benchmark's directory but which ``BENCHMARK.json`` does not run (PERF.md
@@ -43,6 +45,18 @@ HELD_OUT = {"mamba2-780m.dml.k2": ("mamba2-780m.8L", "dml.k2")}
 
 ONE_CHIP = [w["name"] for w in harness.manifest()["workloads"]
             if w["chips"] == 1] + sorted(HELD_OUT)
+FOUR_CHIP = [w["name"] for w in harness.manifest()["workloads"]
+             if w["chips"] == 4]
+CELLS = ONE_CHIP + FOUR_CHIP
+
+
+def on_one_device(monkeypatch) -> None:
+    """Give a cell's program a ``clients`` mesh of one device, whatever
+    number of chips the cell asks for."""
+    from benchmarks.chip import program
+    from repro.launch.mesh import make_client_mesh
+    monkeypatch.setattr(program, "make_client_mesh",
+                        lambda n: make_client_mesh(1))
 
 
 def _cell(cell_name: str) -> harness.Cell:

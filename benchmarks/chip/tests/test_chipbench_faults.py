@@ -9,7 +9,8 @@ program from outside:
   frozen       the round program returns the state it was given;
   half_batch   the round program sees the first half of the private rows
                (its mean is then taken over the rest);
-  no_exchange  the Eq.-2 term receives nothing from the other clients.
+  no_exchange  the Eq.-2 term receives nothing from the other clients
+               (in the one-chip round and in the sharded one).
 
 A training cell produces no tokens or answers to alter, so that fault
 does not apply.  The control is the reference itself computed with
@@ -51,6 +52,8 @@ def no_exchange(pop, fed, monkeypatch):
     from repro.core import distributed as D
     monkeypatch.setattr(D, "_mutual_term", lambda flat, *a, **kw:
                         jnp.zeros(flat.shape[:1], jnp.float32))
+    monkeypatch.setattr(D.ops, "mutual_kl_pair", lambda live, *a, **kw:
+                        jnp.zeros(live.shape[:2], jnp.float32))
 
 
 FAULTS = {"frozen": frozen, "half_batch": half_batch,
@@ -69,9 +72,10 @@ def broken(checks) -> bool:
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-@pytest.mark.parametrize("cell", cells.ONE_CHIP)
+@pytest.mark.parametrize("cell", cells.CELLS)
 def test_a_broken_round_is_not_correct(cell, fault, monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_IMPL", "ref")
+    cells.on_one_device(monkeypatch)
     out = harness.run(cells.tiny(cell), SEED, 0.05, False, on_chip=False,
                       after_build=lambda pop, fed:
                       FAULTS[fault](pop, fed, monkeypatch))
@@ -79,7 +83,7 @@ def test_a_broken_round_is_not_correct(cell, fault, monkeypatch):
     assert broken(out["checks"]), out["checks"]
 
 
-@pytest.mark.parametrize("cell", cells.ONE_CHIP)
+@pytest.mark.parametrize("cell", cells.CELLS)
 def test_the_control_is_not_correct(cell):
     c = cells.tiny(cell)
     ref = ref_dml.Federation(c.family, c.config, c.traffic).run(

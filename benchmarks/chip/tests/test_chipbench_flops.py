@@ -52,6 +52,7 @@ FUSION = ("%fusion.1 = bf16[8192,2560]{1,0:T(8,128)(2,1)} fusion(bf16[2,"
 
 KERNELS = {"flash_attn_fwd_roofline": [FLASH_PRIVATE, FLASH_PUBLIC],
            "kl_mutual_pair_roofline": [KL_DENSE],
+           "sparse_kl_topk_roofline": [KL_SPARSE],
            "ssd_scan_fwd_roofline": [SSD]}
 
 
@@ -84,7 +85,6 @@ def test_each_kernel_matches_only_its_own_calls(metric):
             assert mod.match(T.parse_hlo(text)) == (name == metric), \
                 (metric, text[:40])
     assert not mod.match(T.parse_hlo(FUSION))
-    assert not mod.match(T.parse_hlo(KL_SPARSE))     # SparseDML's kernel
 
 
 def test_flash_attention_counts():
@@ -106,6 +106,20 @@ def test_dense_kl_counts_at_the_true_vocabulary():
     assert mod.flops(call, ctx) == 1_089_077_248
     # live and fixed 2 x 2048 x 18,992 x 2 B each, weights 16, out 16,384
     assert mod.nbytes(call, ctx) == 311_181_328
+
+
+def test_sparse_kl_counts_at_the_true_vocabulary():
+    mod = harness.metric_module("sparse_kl_topk_roofline")
+    call = T.parse_hlo(KL_SPARSE)
+    ctx = ctx_for("qwen3-4b.2L.v1of8")                  # V = 18,992
+    # 4 x (Kl 2) x 2048 positions x 18,992 + 4 x 2 x (J 2) x 2048 x 64
+    assert mod.flops(call, ctx) == 313_262_080
+    # live 2 x 2048 x 18,992 x 2 B; indices and log-probabilities
+    # 2 x 2048 x 64 x 4 B each; weights 16; the result 32 x 2 x 64 x 4 B
+    assert mod.nbytes(call, ctx) == 157_696_016
+    # without a configuration, at the vocabulary the call streams
+    assert mod.nbytes(call) == 2 * 2048 * 19200 * 2 + 2 * 1_048_576 + 16 + \
+        16_384
 
 
 def test_ssd_counts():

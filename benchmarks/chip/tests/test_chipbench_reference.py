@@ -17,9 +17,10 @@ TOL = 1e-4
 
 
 @pytest.mark.parametrize("impl", ["ref", "interpret"])
-@pytest.mark.parametrize("cell", cells.ONE_CHIP)
+@pytest.mark.parametrize("cell", cells.CELLS)
 def test_program_agrees_with_the_reference(cell, impl, monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_IMPL", impl)
+    cells.on_one_device(monkeypatch)
     seen = {}
     out = harness.run(cells.tiny(cell), 2 ** 31 + 7, 0.05, False,
                       on_chip=False,

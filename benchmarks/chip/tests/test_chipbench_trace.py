@@ -56,6 +56,18 @@ def test_the_window_is_on_the_device_clock(window):
     assert window.rounds == 2
 
 
+def test_the_window_skips_the_settling_rounds(trace):
+    # the first run of the round program left out: one round, from the
+    # second run to the third, and its one gap
+    dev, n = trace.devices[0], len(T.rounds(trace))
+    window = T.device_window(dev, n, skip=1)
+    assert (window.lo, window.hi, window.rounds) == (50_358_772, 57_924_814,
+                                                     1)
+    assert T.inter_round_gaps(dev, window.program, window.lo,
+                              window.hi) == [7_557_349]
+    assert T.device_window(dev, n, skip=2) is None
+
+
 def test_busy_time(trace, window):
     # round 0: flash 5,440 and reduce 408 touch (5,848), copy-start 13,
     # copy-done 1,271, fusion 1,801 -> 8,933; round 1: 5,432 + 408 + 13 +

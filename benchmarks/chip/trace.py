@@ -168,8 +168,9 @@ def rounds(trace: Trace) -> List[Event]:
 @dataclasses.dataclass
 class Window:
     """The traced rounds on one device, on the device's own clock: from
-    the start of the first run of the round program to the start of the
-    last, so ``rounds`` whole periods of program and gap."""
+    the start of a run of the round program (the first that is not
+    skipped) to the start of the last, so ``rounds`` whole periods of
+    program and gap."""
     device: Device
     program: str
     lo: float
@@ -177,13 +178,15 @@ class Window:
     rounds: int
 
 
-def device_window(device: Device, n_rounds: int) -> Optional[Window]:
-    """The window of ``device`` over the ``n_rounds`` traced rounds, or
-    None when no program ran once a round."""
+def device_window(device: Device, n_rounds: int, skip: int = 0
+                  ) -> Optional[Window]:
+    """The window of ``device`` over the ``n_rounds`` traced rounds less
+    the first ``skip`` of them, or None when no program ran once a
+    round."""
     prog = round_program(device, n_rounds)
     if prog is None:
         return None
-    runs = sorted(m.start for m in device.modules if m.name == prog)
+    runs = sorted(m.start for m in device.modules if m.name == prog)[skip:]
     if len(runs) < 2:
         return None
     return Window(device, prog, runs[0], runs[-1], len(runs) - 1)
