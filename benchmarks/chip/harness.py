@@ -7,7 +7,13 @@ Everything a cell is made of is found by name:
                                   chips, and the metrics it reports;
   configs/<config>.json           the model: the program's registry entry
                                   and replaced keys, the published values,
-                                  and the reference family (reference/);
+                                  and the reference family;
+  reference/<family>.py           the family's plain reference: ``dims``,
+                                  ``init``, ``forward``, ``to_program`` and
+                                  ``from_program``; ``matmul_params`` and
+                                  ``attention_flops_per_token`` (``mfu``),
+                                  optionally ``attention_pairs`` (the flash
+                                  roofline); ``TINY``, its CPU preset;
   traffic/<traffic>.json          the federated job: strategy, clients,
                                   batch, sequence, public batch, optimizer;
   workloads/<cell>.json           the limits of ``correct`` and the
@@ -179,26 +185,6 @@ def memory_peak(devs) -> int:
     return peak
 
 
-# -- host spans of a traced window --------------------------------------------
-
-def _annotated(fn: Callable, label: str) -> Callable:
-    import jax
-
-    def wrapped(*a, **kw):
-        with jax.profiler.TraceAnnotation(label):
-            return fn(*a, **kw)
-    return wrapped
-
-
-def annotate_host(pop) -> None:
-    """Host spans around the calls the round makes into the population:
-    the batch build and the dispatch of the round program."""
-    for attr in ("_private_batch", "_public_batch"):
-        setattr(pop, attr, _annotated(getattr(pop, attr), "batch build"))
-    for key in list(pop._steps):
-        pop._steps[key] = _annotated(pop._steps[key], "dispatch")
-
-
 # -- one run ------------------------------------------------------------------
 
 def first_rounds(cell: Cell, pop, fed) -> dict:
@@ -307,7 +293,6 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
     r0 = r = fed.round
     trace_dir = None
     if traced:
-        annotate_host(pop)
         trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0

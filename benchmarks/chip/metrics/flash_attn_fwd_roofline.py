@@ -8,9 +8,13 @@ log-sum-exp (..., Hq, S, 1).  Seen in the trace under the HLO names
 ``closed_call.24``/``.28`` (forward) and ``rematted_computation.22``/
 ``.23`` (the forward recomputed in the backward pass) of qwen3-4b's round.
 
-FLOPs: the causal score and value products, 4 x batch x Hq x hd x
-S (S + 1) / 2 (S x T where the keys are not the queries).  Bytes: q, k,
-v, the output and the log-sum-exp.
+FLOPs: the score and value products, 4 x batch x Hq x hd x the
+query-key pairs a call computes.  The pairs are the cell's family's
+``attention_pairs(config, S, T)`` where the family has it: the mean over
+its attention layers, which may differ (a sliding window on some), since
+a window's call and a full one have the same signature.  Otherwise every
+layer is full and causal: S (S + 1) / 2, or S x T where the keys are not
+the queries.  Bytes: q, k, v, the output and the log-sum-exp.
 """
 from benchmarks.chip import roofline as R
 
@@ -35,7 +39,12 @@ def flops(call, ctx=None) -> float:
     batch = R.prod(q.shape[:-3])
     Hq, S, hd = q.shape[-3:]
     T = k.shape[-2]
-    pairs = S * (S + 1) / 2 if S == T else S * T
+    cell = getattr(ctx, "cell", None)
+    counts = getattr(getattr(cell, "family", None), "attention_pairs", None)
+    if counts is not None:
+        pairs = counts(cell.config, S, T)
+    else:
+        pairs = S * (S + 1) / 2 if S == T else S * T
     return 4.0 * batch * Hq * pairs * hd
 
 

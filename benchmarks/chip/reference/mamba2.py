@@ -37,6 +37,16 @@ import jax.numpy as jnp
 
 from .common import (matmul, rms_norm, silu, softplus, truncated_normal)
 
+# the configuration the CPU tests run, in a configuration file's schema:
+# the program's registry ``reduced()`` preset of mamba2-780m, float32
+TINY = {
+    "reference": "mamba2",
+    "program": {"registry": "mamba2-780m", "preset": "reduced"},
+    "d_model": 128, "n_layer": 2, "vocab_size": 512, "d_state": 16,
+    "d_conv": 4, "expand": 2, "headdim": 32, "ngroups": 1,
+    "chunk_size": 32, "dt_min": 0.001, "dt_max": 0.1,
+    "rms_norm_eps": 1e-5}
+
 
 def dims(cfg: dict) -> dict:
     """Sizes by the published names; the embedding has ``vocab_size``

@@ -27,6 +27,17 @@ import jax.numpy as jnp
 
 from .common import matmul, rms_norm, silu, truncated_normal
 
+# the configuration the CPU tests run, in a configuration file's schema:
+# the program's registry ``reduced()`` preset of qwen3-4b, float32
+TINY = {
+    "reference": "qwen3",
+    "program": {"registry": "qwen3-4b", "preset": "reduced",
+                "replace": {"tie_embeddings": True, "rms_eps": 1e-6}},
+    "hidden_size": 128, "intermediate_size": 256,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
+    "num_hidden_layers": 2, "vocab_size": 512, "rope_theta": 1000000,
+    "rms_norm_eps": 1e-6, "tie_word_embeddings": True}
+
 
 def dims(cfg: dict) -> dict:
     return {"d": cfg["hidden_size"], "H": cfg["num_attention_heads"],

@@ -1,7 +1,8 @@
 """Cells of the benchmark cut to a size the CPU tests can hold.
 
-The model is the registry's ``reduced()`` preset of the cell's
-architecture (float32), the traffic is the cell's own with batch 4,
+The model is the ``TINY`` configuration that the cell's reference family
+(``reference/<family>.py``) exports in a configuration file's schema, so
+a new family brings its own; the traffic is the cell's own with batch 4,
 sequence 64 and public batch 2, and the limits are the cell's.  Only the
 sizes differ from what the chip runs.  A four-chip cell's program runs
 its sharded round on a ``clients`` mesh of the one CPU device, which then
@@ -20,25 +21,6 @@ for p in (str(ROOT), str(ROOT / "src")):
         sys.path.insert(0, p)
 
 from benchmarks.chip import harness  # noqa: E402
-
-# the registry's reduced() presets, by the published names
-TINY = {
-    "qwen3": {
-        "reference": "qwen3",
-        "program": {"registry": "qwen3-4b", "preset": "reduced",
-                    "replace": {"tie_embeddings": True, "rms_eps": 1e-6}},
-        "hidden_size": 128, "intermediate_size": 256,
-        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
-        "num_hidden_layers": 2, "vocab_size": 512, "rope_theta": 1000000,
-        "rms_norm_eps": 1e-6, "tie_word_embeddings": True},
-    "mamba2": {
-        "reference": "mamba2",
-        "program": {"registry": "mamba2-780m", "preset": "reduced"},
-        "d_model": 128, "n_layer": 2, "vocab_size": 512, "d_state": 16,
-        "d_conv": 4, "expand": 2, "headdim": 32, "ngroups": 1,
-        "chunk_size": 32, "dt_min": 0.001, "dt_max": 0.1,
-        "rms_norm_eps": 1e-5},
-}
 
 # cell -> (configuration, traffic)
 HELD_OUT = {"mamba2-780m.dml.k2": ("mamba2-780m.8L", "dml.k2")}
@@ -72,7 +54,6 @@ def _cell(cell_name: str) -> harness.Cell:
 
 def tiny(cell_name: str) -> harness.Cell:
     real = _cell(cell_name)
-    config = TINY[real.config["reference"]]
     traffic = dict(real.traffic, batch=4, seq=64, public_batch=2)
-    return harness.Cell(real.name, real.chips, config, traffic, real.limits,
-                        harness.family(config["reference"]), [])
+    return harness.Cell(real.name, real.chips, real.family.TINY, traffic,
+                        real.limits, real.family, [])

@@ -98,6 +98,47 @@ def test_flash_attention_counts():
     assert mod.nbytes(pub) == 84_410_368
 
 
+def test_flash_attention_counts_the_qwen3_family_as_full_layers():
+    # the qwen3 family has no ``attention_pairs``: a cell's context counts
+    # what the call's shapes alone count
+    mod = harness.metric_module("flash_attn_fwd_roofline")
+    ctx = ctx_for("qwen3-4b.2L.v1of8")
+    assert not hasattr(ctx.cell.family, "attention_pairs")
+    assert mod.flops(T.parse_hlo(FLASH_PRIVATE), ctx) == 68_786_585_600
+    assert mod.flops(T.parse_hlo(FLASH_PUBLIC), ctx) == 34_393_292_800
+
+
+def _window_pairs(S: int, window: int) -> int:
+    """Causal pairs of one sequence when each query sees at most the last
+    ``window`` keys, itself included, counted query by query."""
+    return sum(min(i + 1, window) for i in range(S))
+
+
+def test_flash_attention_counts_a_windowed_familys_pairs():
+    # a family of a period S,S,S,F: a 1024 window on three of four layers
+    assert _window_pairs(8192, 1024) == 7_864_832
+    assert _window_pairs(8192, 8192) == 33_558_528
+
+    def attention_pairs(config, S, T):
+        kinds = config["layer_types"]
+        return sum(_window_pairs(S, config["sliding_window"])
+                   if kind == "sliding_attention" else S * (S + 1) // 2
+                   for kind in kinds) / len(kinds)
+
+    config = {"sliding_window": 1024,
+              "layer_types": ["sliding_attention"] * 3 + ["full_attention"]}
+    family = SimpleNamespace(attention_pairs=attention_pairs)
+    ctx = SimpleNamespace(cell=SimpleNamespace(config=config, family=family))
+    call = T.parse_hlo(FLASH_PRIVATE.replace(",1024,", ",8192,"))
+    assert call.operands[0].shape == (2, 4, 32, 8192, 128)
+    mod = harness.metric_module("flash_attn_fwd_roofline")
+    mean_pairs = (3 * 7_864_832 + 33_558_528) / 4
+    assert mod.flops(call, ctx) == 4.0 * 8 * 32 * mean_pairs * 128
+    # a full count would be 2.35 times the period's work
+    assert mod.flops(call) / mod.flops(call, ctx) == pytest.approx(
+        4 * 33_558_528 / (3 * 7_864_832 + 33_558_528))
+
+
 def test_dense_kl_counts_at_the_true_vocabulary():
     mod = harness.metric_module("kl_mutual_pair_roofline")
     call = T.parse_hlo(KL_DENSE)

@@ -1,8 +1,9 @@
-"""``batch_build_ms`` and ``dispatch_ms`` on a trace built by hand.
+"""``batch_build_ms``, ``dispatch_ms`` and ``spans.per_round_stat`` on a
+trace built by hand.
 
 Two traced rounds; times are ns on the trace's clock.  The program's
-spans carry the stat ``round``; the harness's spans of the same names
-enclose them and carry no stats.
+spans carry the stat ``round``; spans of the same names that enclose
+them and carry no stats (as the harness once wrote) are left out.
 
   round 0  batch build  private 10,000,000-50,000,000, public
                         45,000,000-60,000,000 (5 ms overlap): 50 ms
@@ -13,8 +14,9 @@ enclose them and carry no stats.
                         461,000,000-463,000,000: 3 ms
 
 so ``batch_build_ms`` reads (50 + 52) / 2 = 51 and ``dispatch_ms``
-(3 + 3) / 2 = 3.  The harness's spans, were they counted, would change
-both."""
+(3 + 3) / 2 = 3.  The stat-less spans, were they counted, would change
+both.  Of the spans' numeric stats, ``tokens`` sums to 8,192 + 2,048 a
+round and ``programs`` to 1 in round 0 and 2 in round 1."""
 import sys
 from pathlib import Path
 
@@ -24,7 +26,7 @@ ROOT = Path(__file__).resolve().parents[3]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from benchmarks.chip import harness  # noqa: E402
+from benchmarks.chip import harness, spans  # noqa: E402
 from benchmarks.chip import trace as T  # noqa: E402
 
 MS = 1_000_000
@@ -93,6 +95,35 @@ def test_no_program_spans_no_reading(metric):
     # a program that writes no spans of its own: the harness's alone
     assert _read(metric, _harness_spans()) is None
     assert _read(metric, []) is None
+
+
+def _trace():
+    return T.Trace([], sorted(_program_spans() + _harness_spans(),
+                              key=lambda e: e.start))
+
+
+@pytest.mark.parametrize("name,stat,value", [
+    ("batch build", "tokens", 10_240.0),
+    ("dispatch", "programs", 1.5),
+    ("batch build", "programs", None),
+    ("metrics sync", "round", None)])
+def test_per_round_stat_is_the_mean_of_each_rounds_sum(name, stat, value):
+    assert spans.per_round_stat(_trace(), name, stat) == value
+
+
+def test_a_text_stat_is_no_counter():
+    with pytest.raises(ValueError):
+        spans.per_round_stat(_trace(), "batch build", "which")
+
+
+def test_per_round_stat_counts_rounds_that_carry_it():
+    # a round whose spans lack the stat is no round of that counter
+    host = _program_spans() + [_span("dispatch", 800 * MS, 801 * MS,
+                                     round=2)]
+    trace = T.Trace([], sorted(host, key=lambda e: e.start))
+    assert spans.per_round_stat(trace, "dispatch", "programs") == 1.5
+    assert spans.per_round_ms(trace, "dispatch") == pytest.approx(
+        (3 + 3 + 1) / 3)
 
 
 def test_gap_labels_keep_the_spans_names():

@@ -143,6 +143,49 @@ def test_context_numbers(trace, window):
     assert idle == pytest.approx(100 * (1 - 17_616 / 14_402_426))
 
 
+def test_the_xspace_reads_as_profile_data(trace):
+    """``load`` reads the XSpace itself, for the ops' metadata; every
+    event it keeps is the one ``jax.profiler.ProfileData`` gives, to the
+    ns and stat for stat."""
+    from jax.profiler import ProfileData
+    data = ProfileData.from_file(str(FIXTURE))
+    plane = next(p for p in data.planes if p.name == "/device:TPU:0")
+    lines = {ln.name: [(e.name, float(e.start_ns), float(e.duration_ns),
+                        {str(k): str(v) for k, v in e.stats})
+                       for e in ln.events] for ln in plane.lines}
+    dev = trace.devices[0]
+    for line, kept in (("XLA Ops", dev.ops), ("Async XLA Ops", dev.async_ops),
+                       ("XLA Modules", dev.modules)):
+        assert [(e.name, e.start, e.dur, e.stats) for e in kept] == \
+            lines[line]
+    host = next(p for p in data.planes if p.name == "/host:CPU")
+    steps = [(e.name, float(e.start_ns), float(e.duration_ns))
+             for ln in host.lines for e in ln.events if e.name == "round"]
+    assert [(e.name, e.start, e.dur) for e in trace.host] == steps
+
+
+def test_ops_keep_their_name_stack(trace):
+    # the fixture's programs are jitted lambdas with no named scope
+    stacks = {e.tf_op for e in trace.devices[0].ops}
+    assert "jit(<lambda>)/pallas_call:" in stacks
+    assert "jit(<lambda>)/reduce_sum:" in stacks
+    assert "" in stacks                 # copies carry no JAX operation
+    assert all(e.tf_op == "" for e in trace.devices[0].modules)
+
+
+@pytest.mark.parametrize("tf_op,stack,scope", [
+    ("jit(step)/transpose(jvp(eq2))/dot_general:",
+     ["step", "eq2", "dot_general"], "eq2"),
+    ("jit(step)/jvp(private_loss)/vmap(eq2)/mul:",
+     ["step", "private_loss", "eq2", "mul"], "private_loss"),
+    ("jit(<lambda>)/pallas_call:", ["<lambda>", "pallas_call"], None),
+    ("", [""], None)])
+def test_an_op_belongs_to_its_outermost_scope(tf_op, stack, scope):
+    assert T.name_stack(tf_op) == stack
+    ev = T.Event("%op", 0.0, 1.0, {}, tf_op)
+    assert T.scope_of(ev, ("eq2", "private_loss")) == scope
+
+
 def test_union_clip_and_overlap():
     assert T.union([(5, 7), (0, 2), (1, 3), (3, 4)]) == [(0, 4), (5, 7)]
     assert T.clip([(0, 10), (12, 20)], 5, 15) == [(5, 10), (12, 15)]

@@ -10,13 +10,21 @@ holds one plane per chip (``/device:TPU:<n>``) whose lines are
                      the core's operations;
   ``XLA Modules``    one event per run of a compiled program;
 
-and one plane ``/host:CPU`` with the host's annotations, such as the
-harness's ``round`` steps (``jax.profiler.StepTraceAnnotation``).  Host
-and device events share one clock, in nanoseconds, to about a millisecond
-(a chip trace shows device operations up to 1.1 ms before the host step
-that issued them); so the window of the traced rounds is taken on each
+and one plane ``/host:CPU`` with the host's annotations: the harness's
+``round`` steps (``jax.profiler.StepTraceAnnotation``) and the program's
+own spans, each of which carries the stat ``round`` and whatever other
+stats the program writes (``spans.py`` reads them).  Host and device
+events share one clock, in nanoseconds, to about a millisecond (a chip
+trace shows device operations up to 1.1 ms before the host step that
+issued them); so the window of the traced rounds is taken on each
 device's own events (``device_window``), and the host's spans only name
 what the host was doing in a gap.
+
+Each device operation keeps its ``tf_op``, the name stack of the JAX
+operation it was lowered from, as the op's metadata in the XSpace holds
+it (``jax.named_scope`` names appear there, wrapped by the transforms
+applied to them: ``transpose(jvp(eq2))``); ``scope_of`` reads the
+program's scope from it.
 
 An operation's name is the HLO instruction's text, with its result and
 operand shapes; ``parse_hlo`` reads them back, so that a kernel's FLOPs
@@ -25,7 +33,9 @@ and bytes can be counted from the shapes of each call.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import glob
+import importlib.util
 import os
 import re
 from collections import defaultdict
@@ -33,7 +43,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]
 
-# host annotations the harness writes, and the steps' name
+# the harness's steps, and the program's host spans that name idle gaps
 ROUND = "round"
 HOST_SPANS = ("batch build", "dispatch")
 
@@ -44,6 +54,7 @@ class Event:
     start: float                     # ns, on the trace's clock
     dur: float                       # ns
     stats: Dict[str, str] = dataclasses.field(default_factory=dict)
+    tf_op: str = ""                  # a device op's JAX name stack
 
     @property
     def end(self) -> float:
@@ -73,31 +84,74 @@ def find_xplane(directory: str) -> str:
 
 
 def load(path: str) -> Trace:
-    """Read an ``.xplane.pb`` (or the newest one under a directory)."""
-    from jax.profiler import ProfileData
+    """Read an ``.xplane.pb`` (or the newest one under a directory): each
+    chip's lines, and the host's ``round`` steps and every host event that
+    carries a ``round`` stat."""
     if os.path.isdir(path):
         path = find_xplane(path)
-    data = ProfileData.from_file(path)
+    space = _xplane_pb2().XSpace()
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
     devices, host = [], []
-    for plane in data.planes:
+    for plane in space.planes:
         if plane.name.startswith("/device:") and "TPU" in plane.name:
-            lines = {ln.name: [_event(e) for e in ln.events]
-                     for ln in plane.lines}
+            lines = {ln.name: _events(plane, ln) for ln in plane.lines}
             devices.append(Device(plane.name, lines.get("XLA Ops", []),
                                   lines.get("Async XLA Ops", []),
                                   lines.get("XLA Modules", [])))
         elif plane.name == "/host:CPU":
             for ln in plane.lines:
-                host.extend(_event(e) for e in ln.events
-                            if e.name == ROUND or e.name in HOST_SPANS)
+                host.extend(e for e in _events(plane, ln)
+                            if e.name == ROUND or ROUND in e.stats)
     devices.sort(key=lambda d: _device_index(d.name))
     host.sort(key=lambda e: e.start)
     return Trace(devices, host)
 
 
-def _event(e) -> Event:
-    return Event(e.name, float(e.start_ns), float(e.duration_ns),
-                 {str(k): str(v) for k, v in e.stats})
+@functools.lru_cache(maxsize=None)
+def _xplane_pb2():
+    """The XSpace protobuf module that TensorFlow's wheel ships, loaded
+    from its file alone: importing it as ``tensorflow.tsl...`` would start
+    TensorFlow."""
+    spec = importlib.util.find_spec("tensorflow")
+    if spec is None or not spec.submodule_search_locations:
+        raise ImportError("reading a trace needs tensorflow's xplane_pb2")
+    path = os.path.join(spec.submodule_search_locations[0], "tsl",
+                        "profiler", "protobuf", "xplane_pb2.py")
+    mod_spec = importlib.util.spec_from_file_location("_xplane_pb2", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def _events(plane, line) -> List[Event]:
+    """A line's events on the trace's clock, in whole ns as
+    ``jax.profiler.ProfileData`` gives them, with their own stats and the
+    ``tf_op`` of their metadata."""
+    names = {k: m.name for k, m in plane.stat_metadata.items()}
+    meta = plane.event_metadata
+    tf_ops: Dict[int, str] = {}
+    out = []
+    for e in line.events:
+        md = meta[e.metadata_id]
+        ev = Event(md.name, float((line.timestamp_ns * 1000 + e.offset_ps)
+                                  // 1000), float(e.duration_ps // 1000),
+                   {names.get(s.metadata_id, ""): _stat(s, names)
+                    for s in e.stats})
+        if e.metadata_id not in tf_ops:
+            tf_ops[e.metadata_id] = next(
+                (_stat(s, names) for s in md.stats
+                 if names.get(s.metadata_id) == "tf_op"), "")
+        ev.tf_op = tf_ops[e.metadata_id]
+        out.append(ev)
+    return out
+
+
+def _stat(stat, names: Dict[int, str]) -> str:
+    kind = stat.WhichOneof("value")
+    if kind == "ref_value":
+        return names.get(stat.ref_value, "")
+    return str(getattr(stat, kind)) if kind else ""
 
 
 def _device_index(name: str) -> int:
@@ -248,6 +302,30 @@ def labelled_gaps(trace: Trace, device: Device, lo: float, hi: float,
 
 def _module_label(name: str) -> str:
     return name.split("(")[0]
+
+
+# -- scopes -------------------------------------------------------------------
+
+_WRAPPED = re.compile(r"[\w.<>-]+\((.*)\)$")
+
+
+def name_stack(tf_op: str) -> List[str]:
+    """A ``tf_op`` (``jit(step)/transpose(jvp(eq2))/dot_general:``) as its
+    components with each transform unwrapped (``step``, ``eq2``,
+    ``dot_general``); the op type after the last ``:`` is dropped."""
+    out = []
+    for part in tf_op.rsplit(":", 1)[0].split("/"):
+        m = _WRAPPED.match(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPED.match(part)
+        out.append(part)
+    return out
+
+
+def scope_of(ev: Event, scopes: Sequence[str]) -> Optional[str]:
+    """The outermost of ``scopes`` in the op's name stack, or None."""
+    return next((p for p in name_stack(ev.tf_op) if p in scopes), None)
 
 
 # -- HLO calls ----------------------------------------------------------------
